@@ -43,6 +43,8 @@ print("RESULT" + json.dumps(out))
 
 def run() -> dict:
     env = dict(os.environ)
+    # a CPU mesh by design; on a TPU host the parent may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run([sys.executable, "-c", _SCRIPT], capture_output=True,

@@ -13,7 +13,7 @@ VMEM_BUDGET = 16 * 2**20  # bytes per TensorCore
 
 
 def kernel_footprints() -> dict:
-    from repro.kernels.bucketize import BOUNDARY_CHUNK, ROW_TILE
+    from repro.kernels.bucketize import BOUNDARY_CHUNK
     from repro.kernels.decode import G_BLOCK
     from repro.kernels.lognorm import TILE_C, TILE_R
     from repro.kernels.sigridhash import VAL_TILE
@@ -24,8 +24,8 @@ def kernel_footprints() -> dict:
         # name: (in_bytes, out_bytes, scratch_bytes, flops_per_byte)
         "decode_bitpack": (G_BLOCK * w * 4, G_BLOCK * 32 * 4, 0, 2.0),
         "decode_bytesplit": (G_BLOCK * 4 * 4, G_BLOCK * 4 * 4, 0, 1.5),
-        "bucketize": (ROW_TILE * 4 + m * 4, ROW_TILE * 4,
-                      ROW_TILE * BOUNDARY_CHUNK, m / 8.0),
+        "bucketize": (G_BLOCK * 4 * 4 + m * 4, G_BLOCK * 4 * 4,
+                      G_BLOCK * BOUNDARY_CHUNK, m / 8.0),
         "sigridhash": (VAL_TILE * 4 + 8, VAL_TILE * 4, 0, 12 / 8.0),
         "lognorm": (TILE_R * TILE_C * 4, TILE_R * TILE_C * 4, 0, 1 / 8.0),
         "fused_dense": (G_BLOCK * 4 * 4, G_BLOCK * 4 * 4, 0, 2.0),

@@ -30,6 +30,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    from repro.common.util import enable_compile_cache
+
+    enable_compile_cache()
     failures = []
     for name, desc in BENCHES:
         if args.only and name != args.only:

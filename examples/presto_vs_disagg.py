@@ -70,6 +70,8 @@ for placement in ("presto", "hybrid", "disagg"):
 def system_level() -> None:
     print("=== system level (16-device mesh, compiled HLO) ===")
     env = dict(os.environ)
+    # a CPU mesh by design; on a TPU host this process may hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run([sys.executable, "-c", _SH], capture_output=True,

@@ -2,12 +2,34 @@
 
 from __future__ import annotations
 
+import os
 import time
 from contextlib import contextmanager
 from typing import Any, Iterator
 
 import jax
 import numpy as np
+
+# repository root: src/repro/common/util.py -> three levels up from src/
+REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, names the directory and is left
+    alone (JAX reads it itself).  Otherwise the cache lives at one fixed path
+    inside the checkout, ``.jax_cache/`` — the path is part of the cache key,
+    so a directory that moved between runs would never hit.  Entry points
+    call this at the start of ``main``; importing a module never does."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class Timer:

@@ -158,11 +158,11 @@ def default_spill_store(
 
 
 def batch_nbytes(batch: Any) -> int:
-    """Size in bytes of one train-ready mini-batch (dict of arrays)."""
-    try:
-        return sum(int(np.asarray(v).nbytes) for v in batch.values())
-    except Exception:
-        return 0
+    """Size in bytes of one train-ready mini-batch (dict of arrays).
+
+    Read from each array's own ``nbytes``: a device array is sized where it
+    lives, never copied to the host to be measured."""
+    return sum(int(v.nbytes) for v in batch.values())
 
 
 class FeatureCache:

@@ -40,10 +40,10 @@ from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import shard_map
 from repro.core.execcache import EXECUTABLES, ExecKey, mesh_key
 from repro.core.opgraph import (
     FAMILIES,

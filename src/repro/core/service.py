@@ -98,6 +98,7 @@ from repro.core.planner import (
 from repro.core.preprocess import stack_pages
 from repro.core.presto import PreStoEngine
 from repro.core.spec import TransformSpec
+from repro.data.columnar import CorruptPartitionFile
 from repro.data.loader import SessionQueue
 from repro.data.storage import (
     DeviceFleet,
@@ -124,6 +125,11 @@ MAX_DEMAND_UNITS = 64  # sanity cap on a single job's ceil(T/P) estimate
 # default byte budget for pages staged AHEAD of their claims (per session);
 # deep-lookahead pre-staging stops, never stalls, when the budget is full
 DEFAULT_STAGE_BUDGET_BYTES = 256 << 20
+# storage faults the block tier treats as a cache miss: the claim's own
+# produce reads the same partition, meets the fault there, and retries or
+# quarantines it.  Any other error (device, compile, out of memory) is real
+# and reaches the consumer.
+_STORAGE_MISSES = (IoFaultError, CorruptPartitionFile, OSError)
 
 
 @dataclasses.dataclass
@@ -1116,7 +1122,10 @@ class Session:
         defeat the re-issue.  Hit/miss counts feed the planner's demand
         discount: when this session's discounted demand changes, the pool
         re-plans so the units its hits freed go to cold jobs."""
-        key = self._cache_key(pid)
+        try:
+            key = self._cache_key(pid)
+        except _STORAGE_MISSES:
+            return None  # unfingerprintable: the produce's read decides
         if not fresh:
             # straggler backup: peek only (never follow the possibly-stuck
             # leader), and keep it out of the hit-rate tallies — the fresh
@@ -1147,7 +1156,15 @@ class Session:
         if changed:
             self._service._request_replan()
         if found is None and status == "produce":
-            assembled = self._assemble_from_blocks(pid)
+            try:
+                assembled = self._assemble_from_blocks(pid)
+            except BaseException as exc:
+                # release this claim's leader lease so followers see the
+                # error instead of waiting on a produce that never comes
+                with self._slock:
+                    self._cache_keys.pop(pid, None)
+                self._cache.abandon(key, exc)
+                raise
             if assembled is not None:
                 # the claim is served without a produce after all: flip the
                 # miss to a hit, release the leader lease by fulfilling it
@@ -1172,8 +1189,9 @@ class Session:
         produce: the per-sample families run through the engine's compiled
         partial program over a fresh (unique-bytes-charged) page read, and
         the hashed sparse blocks gather-expand from the cache — bitwise
-        identical to a cold produce.  Returns None on any miss or error
-        (the claim then produces normally)."""
+        identical to a cold produce.  Returns None on a cache miss or a
+        storage fault (the claim then produces normally); device, compile
+        and out-of-memory errors propagate to the claim's consumer."""
         if self._block_key_parts is None:
             return None
         store, engine = self.job.store, self.engine
@@ -1188,35 +1206,35 @@ class Session:
             if blocks is None:
                 return None
             pages = engine.stage_partition(store, pid)
-            if "sparse_refs" not in pages:
-                return None
-            batch = engine.assemble_from_blocks(pages, *blocks)
-            jax.block_until_ready(batch)
-            return batch
-        except Exception:
+        except _STORAGE_MISSES:
             return None
+        if "sparse_refs" not in pages:
+            return None
+        batch = engine.assemble_from_blocks(pages, *blocks)
+        jax.block_until_ready(batch)
+        return batch
 
     def _publish_blocks(self, pid: int, batch: Any) -> None:
         """Publish a cold produce's unique hashed sparse blocks (winner path).
 
         Classic (dup-factor-1) data short-circuits on the store's None
-        fingerprints.  Publishing must never take the worker thread down."""
+        fingerprints, and unreadable block metadata publishes nothing; any
+        other error is the batch's own and propagates (``_on_produced``
+        routes it to the claim's consumer)."""
         if self._block_key_parts is None:
             return
+        store = self.job.store
         try:
-            store = self.job.store
             fps = store.block_fingerprints(pid)
-            if not fps:
-                return
-            refs = store.block_refs(pid)
-            if refs is None:
-                return
-            ids, lens = self.engine.extract_blocks(batch, refs)
-            plan_hash, placement = self._block_key_parts
-            for fp, bi, bl in zip(fps, ids, lens):
-                self._cache.put_block(BlockKey(fp, plan_hash, placement), bi, bl)
-        except Exception:
+            refs = store.block_refs(pid) if fps else None
+        except _STORAGE_MISSES:
             return
+        if not fps or refs is None:
+            return
+        ids, lens = self.engine.extract_blocks(batch, refs)
+        plan_hash, placement = self._block_key_parts
+        for fp, bi, bl in zip(fps, ids, lens):
+            self._cache.put_block(BlockKey(fp, plan_hash, placement), bi, bl)
         with self._slock:
             self._blocks_published += len(fps)
 
@@ -1239,6 +1257,18 @@ class Session:
                 self._fleet[self._owner_of(pid)].charge_compute(self._costs.ops)
             else:
                 self._fleet.charge_host(self._costs.link_bytes, self._costs.ops)
+        if self._cache_key is not None:
+            with self._slock:
+                leader = pid in self._cache_keys
+            if leader:
+                # publish BEFORE delivery: an error extracting the batch's
+                # blocks is the batch's own, and reaches the consumer through
+                # this claim's future instead of killing the worker thread
+                try:
+                    self._publish_blocks(pid, batch)
+                except Exception as exc:  # noqa: BLE001 — consumer re-raises
+                    self._on_produce_error(pid, exc)
+                    return
         winner = self._queue.complete(pid, batch)
         if winner and self._cache_key is not None:
             # winner-only pop: a straggler loser racing here must not steal
@@ -1254,7 +1284,6 @@ class Session:
                     self._cache.fulfill(key, batch)
                 except Exception:
                     self._cache.abandon(key)
-                self._publish_blocks(pid, batch)
         rows = _batch_rows(batch)
         demand_changed = False
         with self._slock:

@@ -442,8 +442,11 @@ class SessionQueue:
             if self.lookup is not None:
                 try:
                     found = self.lookup(pid, fresh)
-                except Exception:
-                    found = None  # a broken cache probe degrades to a miss
+                except Exception as exc:  # noqa: BLE001 — consumer re-raises
+                    # the probe's own misses return None; what it raises
+                    # (device, compile, out of memory) belongs to the consumer
+                    self.complete_error(pid, exc)
+                    continue
                 if isinstance(found, Future):
                     self._pend(pid, found)
                     continue

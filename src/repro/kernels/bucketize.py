@@ -8,13 +8,15 @@ over boundary chunks.  Napkin math for why this beats binary search on TPU:
 * binary search = log2(m) data-dependent gathers; VMEM gathers with vector
   indices are unsupported/slow on the VPU.
 * compare-and-count = m compares/element on 8x128 lanes.  At ~7.7e12 vector
-  ops/s/chip, a (1024-value, m=4096) tile costs ~0.5 us and the kernel stays
+  ops/s/chip, a (512-value, m=4096) tile costs ~0.3 us and the kernel stays
   entirely compute-local: each HBM byte of feature data is read exactly once
   (Pallas grid pipelining double-buffers the next tile during compute — the
   paper's double-buffering, for free).
 
 Inter-feature parallelism = grid dim 0 (one boundary set per feature).
-Intra-feature parallelism = 8x128 vector lanes + grid dim 1 over row tiles.
+Intra-feature parallelism = 8x128 vector lanes + grid dim 1 over row tiles,
+laid out like the dense pages: (G, 4) value groups per block, so the fused
+generation kernel (``fused.fused_gen_pallas``) shares ``count_le``.
 """
 
 from __future__ import annotations
@@ -25,47 +27,64 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-ROW_TILE = 1024  # values per grid step (8 sublanes x 128 lanes)
+from repro.kernels.decode import G_BLOCK
+
 BOUNDARY_CHUNK = 512  # boundaries reduced per inner-loop iteration
 
 
-def _bucketize_kernel(vals_ref, bounds_ref, out_ref, *, m: int):
-    a = vals_ref[0, :]  # (ROW_TILE,) f32
+def count_le(x: jax.Array, bounds_ref, m: int) -> jax.Array:
+    """Bucket ids of a (G, C) f32 tile: ``#{j : b[j] <= x}`` against the m
+    sorted boundaries in ``bounds_ref``'s (1, 1, m) block, int32 (G, C).
+
+    One (G, 1) value column at a time broadcasts against a (1, chunk) row of
+    boundaries, so no value vector is ever reshaped across the lane/sublane
+    layout (Mosaic's layouts hold on the chip)."""
     nchunks = m // BOUNDARY_CHUNK
-
-    def body(k, acc):
-        b = bounds_ref[0, pl.ds(k * BOUNDARY_CHUNK, BOUNDARY_CHUNK)]
-        cmp = a[:, None] >= b[None, :]
-        return acc + jnp.sum(cmp, axis=1, dtype=jnp.int32)
-
-    acc = jnp.zeros((ROW_TILE,), jnp.int32)
-    if nchunks > 0:
-        acc = jax.lax.fori_loop(0, nchunks, body, acc)
     rem = m - nchunks * BOUNDARY_CHUNK
-    if rem:
-        b = bounds_ref[0, pl.ds(nchunks * BOUNDARY_CHUNK, rem)]
-        acc = acc + jnp.sum(a[:, None] >= b[None, :], axis=1, dtype=jnp.int32)
-    out_ref[0, :] = acc
+
+    def count(col, b):
+        return jnp.sum(col >= b, axis=1, keepdims=True, dtype=jnp.int32)
+
+    counts = []
+    for c in range(x.shape[1]):
+        col = x[:, c : c + 1]  # (G, 1)
+
+        def body(i, acc, col=col):
+            b = bounds_ref[0, :, pl.ds(i * BOUNDARY_CHUNK, BOUNDARY_CHUNK)]
+            return acc + count(col, b)
+
+        acc = jnp.zeros(col.shape, jnp.int32)
+        if nchunks:
+            acc = jax.lax.fori_loop(0, nchunks, body, acc)
+        if rem:
+            acc = acc + count(col, bounds_ref[0, :, pl.ds(nchunks * BOUNDARY_CHUNK, rem)])
+        counts.append(acc)
+    return jnp.concatenate(counts, axis=1)
+
+
+def _bucketize_kernel(vals_ref, bounds_ref, out_ref, *, m: int):
+    out_ref[0] = count_le(vals_ref[0], bounds_ref, m)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bucketize_pallas(
     values: jax.Array, boundaries: jax.Array, *, interpret: bool = False
 ) -> jax.Array:
-    """values (F, R) f32 with R % ROW_TILE == 0; boundaries (F, m) sorted f32
-    (pad with +inf to a lane multiple).  Returns (F, R) int32 in [0, m]."""
-    f, r = values.shape
-    _, m = boundaries.shape
-    assert r % ROW_TILE == 0, (r, ROW_TILE)
-    grid = (f, r // ROW_TILE)
+    """values (F, G, 4) f32 with G % G_BLOCK == 0 (the dense pages' group
+    geometry); boundaries (F, 1, m) sorted f32 (pad with +inf to a lane
+    multiple; the unit middle axis keeps the block's last two dims full).
+    Returns (F, G, 4) int32 in [0, m]."""
+    f, g, c = values.shape
+    _, _, m = boundaries.shape
+    assert g % G_BLOCK == 0, (g, G_BLOCK)
     return pl.pallas_call(
         functools.partial(_bucketize_kernel, m=m),
-        out_shape=jax.ShapeDtypeStruct((f, r), jnp.int32),
-        grid=grid,
+        out_shape=jax.ShapeDtypeStruct((f, g, c), jnp.int32),
+        grid=(f, g // G_BLOCK),
         in_specs=[
-            pl.BlockSpec((1, ROW_TILE), lambda i, j: (i, j)),
-            pl.BlockSpec((1, m), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, G_BLOCK, c), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1, m), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, ROW_TILE), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((1, G_BLOCK, c), lambda i, j: (i, j, 0)),
         interpret=interpret,
     )(values, boundaries)

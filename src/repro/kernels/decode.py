@@ -22,23 +22,34 @@ from jax.experimental import pallas as pl
 G_BLOCK = 128  # groups per grid step
 
 
+def _lane_iota(n: int) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.uint32, (1, n), 1)
+
+
 def _bitunpack_body(p: jax.Array, width: int) -> jax.Array:
-    """(G, w) uint32 words -> (G, 32) uint32 values; static shifts only."""
+    """(G, w) uint32 words -> (G, 32) uint32 values; static shifts only.
+
+    Value j of a group sits at bit ``j*w``: its low part in word
+    ``(j*w) >> 5`` shifted right by ``(j*w) & 31``, and — when it straddles
+    a word boundary — its high part in the next word, shifted left.  The
+    body walks the w words once; each (G, 1) word column broadcasts across
+    the 32 output lanes, and per-lane shift amounts and word selectors come
+    from a lane iota.  No 1-D vector and no lane concatenation ever forms,
+    so Mosaic's layouts hold on the chip."""
     w = width
     mask = jnp.uint32(0xFFFFFFFF) if w == 32 else jnp.uint32((1 << w) - 1)
-    cols = []
-    for j in range(32):
-        bit = j * w
-        wid, off = bit >> 5, bit & 31
-        lo = p[:, wid] >> jnp.uint32(off)
-        if off == 0:
-            val = lo
-        elif off + w > 32:
-            val = lo | (p[:, wid + 1] << jnp.uint32(32 - off))
-        else:
-            val = lo
-        cols.append((val & mask)[:, None])
-    return jnp.concatenate(cols, axis=1)
+    bit = _lane_iota(32) * jnp.uint32(w)
+    lo_word, off = bit >> 5, bit & jnp.uint32(31)
+    straddles = (off != 0) & (off + jnp.uint32(w) > 32)
+    hi_shift = jnp.where(straddles, jnp.uint32(32) - off, jnp.uint32(0))
+    out = jnp.zeros((p.shape[0], 32), jnp.uint32)
+    for i in range(w):
+        word = p[:, i : i + 1]  # (G, 1)
+        out = out | jnp.where(lo_word == i, word >> off, jnp.uint32(0))
+        if i:
+            hi = straddles & (lo_word == i - 1)
+            out = out | jnp.where(hi, word << hi_shift, jnp.uint32(0))
+    return out & mask
 
 
 def _bitunpack_kernel(p_ref, o_ref, *, width: int):
@@ -63,16 +74,16 @@ def bitunpack_pallas(
 
 
 def _bytesplit_body(p: jax.Array) -> jax.Array:
-    """(G, 4) plane words -> (G, 4) f32 values."""
-    cols = []
-    for j in range(4):
-        sh = jnp.uint32(8 * j)
-        b0 = (p[:, 0] >> sh) & jnp.uint32(0xFF)
-        b1 = (p[:, 1] >> sh) & jnp.uint32(0xFF)
-        b2 = (p[:, 2] >> sh) & jnp.uint32(0xFF)
-        b3 = (p[:, 3] >> sh) & jnp.uint32(0xFF)
-        cols.append((b0 | (b1 << 8) | (b2 << 16) | (b3 << 24))[:, None])
-    words = jnp.concatenate(cols, axis=1)
+    """(G, 4) plane words -> (G, 4) f32 values.
+
+    Value j of group g takes byte j of plane word k as its byte k; each
+    (G, 1) plane column broadcasts across the 4 output lanes with a per-lane
+    shift, as in ``_bitunpack_body``."""
+    shift = _lane_iota(4) * jnp.uint32(8)
+    words = jnp.zeros(p.shape, jnp.uint32)
+    for k in range(4):
+        byte = (p[:, k : k + 1] >> shift) & jnp.uint32(0xFF)
+        words = words | (byte << jnp.uint32(8 * k))
     return jax.lax.bitcast_convert_type(words, jnp.float32)
 
 

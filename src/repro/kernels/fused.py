@@ -19,13 +19,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.bucketize import count_le
 from repro.kernels.decode import G_BLOCK, _bitunpack_body, _bytesplit_body
+from repro.kernels.lognorm import log1p_body
 from repro.kernels.sigridhash import hash_body
 
 
 def _fused_dense_kernel(p_ref, o_ref):
-    x = _bytesplit_body(p_ref[0])
-    o_ref[0] = jnp.log1p(jnp.maximum(x, 0.0))
+    o_ref[0] = log1p_body(_bytesplit_body(p_ref[0]))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -45,7 +46,7 @@ def fused_dense_pallas(plane_words: jax.Array, *, interpret: bool = False) -> ja
 
 def _fused_sparse_kernel(p_ref, params_ref, o_ref, *, width: int):
     ids = _bitunpack_body(p_ref[0], width)  # (G, 32) uint32
-    o_ref[0] = hash_body(ids, params_ref[0, 0], params_ref[0, 1])
+    o_ref[0] = hash_body(ids, params_ref[0, 0, 0], params_ref[0, 0, 1])
 
 
 def _fused_gen_kernel(p_ref, bounds_ref, params_ref, o_ref, *, m: int):
@@ -55,38 +56,22 @@ def _fused_gen_kernel(p_ref, bounds_ref, params_ref, o_ref, *, m: int):
     §Perf (preprocess cell): the unfused path writes/rereads the raw dense
     values and the bucket ids; fusing the whole generated-feature chain
     keeps both intermediates in VMEM (3 HBM round trips -> 1)."""
-    x = _bytesplit_body(p_ref[0])  # (G, 4) f32 raw dense values
-    vals = x.reshape(-1)  # (G*4,)
-    chunk = 512
-    nchunks = m // chunk
-
-    def body(i, acc):
-        b = bounds_ref[0, pl.ds(i * chunk, chunk)]
-        return acc + jnp.sum(vals[:, None] >= b[None, :], axis=1, dtype=jnp.int32)
-
-    acc = jnp.zeros((vals.shape[0],), jnp.int32)
-    if nchunks:
-        acc = jax.lax.fori_loop(0, nchunks, body, acc)
-    rem = m - nchunks * chunk
-    if rem:
-        b = bounds_ref[0, pl.ds(nchunks * chunk, rem)]
-        acc = acc + jnp.sum(vals[:, None] >= b[None, :], axis=1, dtype=jnp.int32)
-    hashed = hash_body(
-        acc.astype(jnp.uint32), params_ref[0, 0], params_ref[0, 1]
+    buckets = count_le(_bytesplit_body(p_ref[0]), bounds_ref, m)  # (G, 4)
+    o_ref[0] = hash_body(
+        buckets.astype(jnp.uint32), params_ref[0, 0, 0], params_ref[0, 0, 1]
     )
-    o_ref[0] = hashed.reshape(x.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_gen_pallas(
     plane_words: jax.Array,  # (F, G, 4) encoded dense words (gen sources)
-    boundaries: jax.Array,  # (F, m) sorted bucket boundaries
-    params: jax.Array,  # (F, 2) uint32 [seed, max]
+    boundaries: jax.Array,  # (F, 1, m) sorted bucket boundaries
+    params: jax.Array,  # (F, 1, 2) uint32 [seed, max]
     *,
     interpret: bool = False,
 ) -> jax.Array:
     f, g, four = plane_words.shape
-    _, m = boundaries.shape
+    _, _, m = boundaries.shape
     assert four == 4 and g % G_BLOCK == 0, plane_words.shape
     return pl.pallas_call(
         functools.partial(_fused_gen_kernel, m=m),
@@ -94,8 +79,8 @@ def fused_gen_pallas(
         grid=(f, g // G_BLOCK),
         in_specs=[
             pl.BlockSpec((1, G_BLOCK, 4), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, m), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 2), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G_BLOCK, 4), lambda i, j: (i, j, 0)),
         interpret=interpret,
@@ -106,7 +91,7 @@ def fused_gen_pallas(
 def fused_sparse_pallas(
     packed: jax.Array, params: jax.Array, *, width: int, interpret: bool = False
 ) -> jax.Array:
-    """packed (F, G, w) uint32, params (F, 2) uint32 [seed, max] -> (F, G, 32) i32."""
+    """packed (F, G, w) uint32, params (F, 1, 2) uint32 [seed, max] -> (F, G, 32) i32."""
     f, g, w = packed.shape
     assert w == width and g % G_BLOCK == 0, (packed.shape, width)
     return pl.pallas_call(
@@ -115,7 +100,7 @@ def fused_sparse_pallas(
         grid=(f, g // G_BLOCK),
         in_specs=[
             pl.BlockSpec((1, G_BLOCK, w), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 2), lambda i, j: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G_BLOCK, 32), lambda i, j: (i, j, 0)),
         interpret=interpret,

@@ -17,8 +17,42 @@ TILE_R = 8
 TILE_C = 1024
 
 
+# cephes logf: on m in [sqrt(1/2), sqrt(2)), log(m) = t + t^3 P(t) - t^2/2
+# with t = m - 1; ln 2 split so that e * _LN2_HI is exact for any exponent e
+_LOG_POLY = (
+    7.0376836292e-2, -1.1514610310e-1, 1.1676998740e-1, -1.2420140846e-1,
+    1.4249322787e-1, -1.6668057665e-1, 2.0000714765e-1, -2.4999993993e-1,
+    3.3333331174e-1,
+)
+_LN2_HI, _LN2_LO = 0.693359375, -2.12194440e-4
+
+
+def log1p_body(x: jax.Array) -> jax.Array:
+    """log1p(max(x, 0)) in f32 lane ops only: exponent and mantissa come
+    from the bits of u = 1 + x, log(mantissa) from a degree-9 polynomial.
+
+    Mosaic's own ``log1p`` lowering was measured 1.07e-4 off numpy on a TPU
+    v5e for values near 10; this body stays within 1e-6 of numpy's float32
+    ``log1p`` (within one ulp at the largest dense values, and within 6e-8
+    near 0, where rounding u costs the relative precision of tiny x)."""
+    u = 1.0 + jnp.maximum(x, 0.0)
+    bits = jax.lax.bitcast_convert_type(u, jnp.int32)
+    e = ((bits >> 23) & 0xFF) - 126
+    m = jax.lax.bitcast_convert_type((bits & 0x007FFFFF) | 0x3F000000, jnp.float32)
+    low = m < 0.70710677  # m in [1/2, 1): fold to [sqrt(1/2), sqrt(2))
+    e = jnp.where(low, e - 1, e).astype(jnp.float32)
+    t = jnp.where(low, m + m - 1.0, m - 1.0)
+    z = t * t
+    poly = jnp.float32(_LOG_POLY[0])
+    for c in _LOG_POLY[1:]:
+        poly = poly * t + jnp.float32(c)
+    y = poly * t * z + jnp.float32(_LN2_LO) * e - 0.5 * z
+    out = (t + y) + jnp.float32(_LN2_HI) * e
+    return jnp.where(u == jnp.inf, u, out)
+
+
 def _lognorm_kernel(x_ref, o_ref):
-    o_ref[...] = jnp.log1p(jnp.maximum(x_ref[...], 0.0))
+    o_ref[...] = log1p_body(x_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -3,9 +3,10 @@
 Seeded avalanche hash + range reduction, elementwise over sparse ids.  TPU
 lanes are 32-bit so we use a murmur3-finalizer mix (see kernels/ref.py for
 the contract note).  One HBM read + one HBM write per element; fully
-VPU-bound.  Per-feature (seed, max_value) pairs ride in as a tiny (F, 2)
-param array — grid dim 0 is the feature (inter-feature parallelism), the
-8x128 lanes cover ids (intra-feature parallelism).
+VPU-bound.  Per-feature (seed, max_value) pairs ride in as a tiny (F, 1, 2)
+param array (unit middle axis, like the values) — grid dim 0 is the feature
+(inter-feature parallelism), the 8x128 lanes cover ids (intra-feature
+parallelism).
 """
 
 from __future__ import annotations
@@ -35,25 +36,26 @@ def hash_body(v: jax.Array, seed: jax.Array, d: jax.Array) -> jax.Array:
 
 
 def _hash_kernel(vals_ref, params_ref, out_ref):
-    v = vals_ref[0, :].astype(jnp.uint32)
-    out_ref[0, :] = hash_body(v, params_ref[0, 0], params_ref[0, 1])
+    v = vals_ref[0].astype(jnp.uint32)  # (1, VAL_TILE)
+    out_ref[0] = hash_body(v, params_ref[0, 0, 0], params_ref[0, 0, 1])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sigridhash_pallas(
     values: jax.Array, params: jax.Array, *, interpret: bool = False
 ) -> jax.Array:
-    """values (F, N) int32, params (F, 2) uint32 [seed, max_value] -> (F, N) i32."""
-    f, n = values.shape
+    """values (F, 1, N) int32, params (F, 1, 2) uint32 [seed, max_value] ->
+    (F, 1, N) i32 (unit middle axis: Mosaic-legal (1, n) blocks)."""
+    f, _, n = values.shape
     assert n % VAL_TILE == 0, (n, VAL_TILE)
     return pl.pallas_call(
         _hash_kernel,
-        out_shape=jax.ShapeDtypeStruct((f, n), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((f, 1, n), jnp.int32),
         grid=(f, n // VAL_TILE),
         in_specs=[
-            pl.BlockSpec((1, VAL_TILE), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, VAL_TILE), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((1, 1, 2), lambda i, j: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, VAL_TILE), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((1, 1, VAL_TILE), lambda i, j: (i, 0, j)),
         interpret=interpret,
     )(values, params)

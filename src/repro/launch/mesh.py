@@ -7,28 +7,23 @@ Multi pod  : (2, 16, 16) = 512 chips, axes (pod, data, model); `pod` is the
 FUNCTIONS, not module constants: importing this module must never touch
 jax device state (the dry-run sets XLA_FLAGS before any jax init).
 
-``make_mesh`` is the version-compat constructor every mesh in the repo goes
-through: newer jax wants explicit ``axis_types`` (all Auto here), older jax
-(<= 0.4.x) has neither ``jax.sharding.AxisType`` nor the kwarg.
+``make_mesh`` is the constructor every mesh in the repo goes through: all
+axes are ``Auto`` (sharding propagated by the compiler), never ``Explicit``.
 """
 
 from __future__ import annotations
-
-import inspect
 
 import jax
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """Build a Mesh, passing ``axis_types`` only where the install supports it."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if hasattr(jax.sharding, "AxisType") and (
-        "axis_types" in inspect.signature(jax.make_mesh).parameters
-    ):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """Build a Mesh whose axes are all ``AxisType.Auto``."""
+    return jax.make_mesh(
+        tuple(shape),
+        tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -64,6 +64,7 @@ import time
 
 import numpy as np
 
+from repro.common.util import enable_compile_cache
 from repro.configs.registry import get_recsys
 from repro.core.costmodel import ContentionAwareCostModel
 from repro.core.ctrlplane import Autoscaler, AutoscalePolicy, parse_kill_spec
@@ -325,6 +326,7 @@ def main(argv=None) -> None:
     ap.add_argument("--events-out", default=None, metavar="PATH",
                     help="write the structured event stream as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     workers = args.workers if args.workers is not None else args.jobs + 1
     kills = [parse_kill_spec(s) for s in (args.kill or [])]

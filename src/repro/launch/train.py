@@ -139,6 +139,9 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--store-root", default=None)
     args = ap.parse_args()
+    from repro.common.util import enable_compile_cache
+
+    enable_compile_cache()
     if args.mode == "recsys":
         train_recsys(args)
     else:

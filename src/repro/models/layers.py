@@ -19,9 +19,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.common.compat import shard_map
 
 from repro.distributed.sharding import ShardingRules
 

@@ -28,8 +28,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-from repro.common.compat import shard_map
+from jax import shard_map
 
 from repro.distributed.sharding import ShardingRules
 from repro.models.layers import ParamDef, Schema, load_weight

@@ -18,9 +18,8 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.common.compat import shard_map
 
 from repro.data.synth import RMDataConfig
 from repro.distributed.sharding import ShardingRules

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common import compat
-
 
 def quantize_int8(g: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """g -> (q int8, scale f32 scalar, residual)."""
@@ -39,21 +37,19 @@ def init_error_state(params: Any) -> Any:
 
 
 def crosspod_compressed_mean(
-    grads: Any, err: Any, axis: str = "pod", axis_index: Any = None
+    grads: Any, err: Any, axis: str = "pod"
 ) -> Tuple[Any, Any]:
     """Inside a shard_map manual over `axis`: compressed mean of grads.
 
     grads are pod-local means; returns (global mean approx, new error state).
-    ``axis_index`` (this shard's position on `axis`, as traced data) is
-    required on old jax — see ``compat.all_gather``.
     """
-    npods = compat.axis_size(axis)
+    npods = jax.lax.axis_size(axis)
 
     def one(g, e):
         q, scale, residual = quantize_int8(g + e)
         # int8 over DCN (s8 collective operands in the compiled HLO)
-        q_all = compat.all_gather(q, axis, axis_index=axis_index)  # (npods, ...)
-        s_all = compat.all_gather(scale, axis, axis_index=axis_index)  # (npods,)
+        q_all = jax.lax.all_gather(q, axis)  # (npods, ...)
+        s_all = jax.lax.all_gather(scale, axis)  # (npods,)
         deq = q_all.astype(jnp.float32) * s_all.reshape(
             (npods,) + (1,) * g.ndim
         )

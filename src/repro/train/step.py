@@ -21,10 +21,9 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro.common.compat import shard_map
 
 from repro.distributed.sharding import ShardingRules
 from repro.train.compression import crosspod_compressed_mean, init_error_state
@@ -169,15 +168,11 @@ def make_compressed_train_step(
     def train_step(state, batch):
         batch_specs = batch_pspec_fn(batch)
 
-        def pod_body(pod_ids, params, opt, step, err, batch_pod):
+        def pod_body(params, opt, step, err, batch_pod):
             (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch_pod
             )
-            # pod_ids is P("pod")-sharded arange: pod_ids[0] is this pod's
-            # index, needed by the old-jax all_gather fallback (see compat)
-            grads, err = crosspod_compressed_mean(
-                grads, err, "pod", axis_index=pod_ids[0]
-            )
+            grads, err = crosspod_compressed_mean(grads, err, "pod")
             updates, opt, om = optimizer.update(grads, opt, params)
             params = apply_updates(params, updates)
             return params, opt, step + 1, err, {**metrics, **om}
@@ -201,15 +196,14 @@ def make_compressed_train_step(
         )
         replicated = jax.tree.map(lambda _: P(), state["params"])
         opt_rep = jax.tree.map(lambda _: P(), state["opt"])
-        pod_ids = jnp.arange(npods, dtype=jnp.int32)
         out = shard_map(
             pod_body,
             mesh=mesh,
             axis_names={"pod"},
-            in_specs=(P("pod"), replicated, opt_rep, P(), replicated, batch_specs),
+            in_specs=(replicated, opt_rep, P(), replicated, batch_specs),
             out_specs=(replicated, opt_rep, P(), replicated, metric_specs),
             check_vma=False,
-        )(pod_ids, state["params"], state["opt"], state["step"], state["err"], batch)
+        )(state["params"], state["opt"], state["step"], state["err"], batch)
         params, opt, step, err, metrics = out
         return dict(params=params, opt=opt, step=step, err=err), metrics
 

@@ -15,8 +15,12 @@ def rng():
 
 
 def run_sharded(script: str, devices: int = 8, timeout: int = 420) -> str:
-    """Run a python snippet in a subprocess with N fake devices."""
+    """Run a python snippet in a subprocess with N fake CPU devices.
+
+    The child is pinned to the CPU backend: on a TPU host it would otherwise
+    contend for the chip this process may hold."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     proc = subprocess.run(

@@ -356,3 +356,52 @@ def test_service_cross_tenant_block_assembly():
             np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]))
     # the store charged unique bytes throughout
     assert store.bytes_read < store.logical_bytes_read
+
+
+class _OomAssembly(PreStoEngine):
+    def assemble_from_blocks(self, *args, **kwargs):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+
+class _UnreadableBlockMeta(PartitionedStore):
+    def block_fingerprints(self, partition_id):
+        raise OSError("block metadata unreadable")
+
+
+@pytest.mark.parametrize("fault", ["device", "storage"])
+def test_block_tier_faults(fault):
+    """A storage fault in the block-tier probe is a miss (the claim produces
+    cold, bitwise); a device error assembling from cached blocks is real and
+    reaches the consumer instead of hiding behind a cold produce."""
+    cfg = _dedup_cfg(dup_factor=4, dup_pool=16)
+    src = SyntheticRecSysSource(cfg, seed=3)
+    spec = TransformSpec.from_source(src)
+    store = PartitionedStore(16, num_devices=2, source=src)
+    eng = PreStoEngine(spec, interpret=True)
+    if fault == "device":
+        eng_b, store_b = _OomAssembly(spec, interpret=True), store
+    else:
+        eng_b, store_b = eng, _UnreadableBlockMeta(16, num_devices=2, source=src)
+    with PreprocessingService(
+        num_workers=2, cache=FeatureCache(capacity_bytes=64 << 20)
+    ) as svc:
+        sA = svc.submit(JobSpec(name="A", spec=spec, store=store, engine=eng,
+                                partitions=range(8)))
+        dict(iter(sA))
+        assert sA.stats().blocks_published > 0
+        sB = svc.submit(JobSpec(name="B", spec=spec, store=store_b, engine=eng_b,
+                                partitions=range(8, 16)))
+        if fault == "device":
+            with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+                dict(iter(sB))
+            sB.cancel()
+            return
+        outB = dict(iter(sB))
+        assert sB.stats().block_hits == 0
+    ref = PreStoEngine(spec, interpret=True, use_exec_cache=False)
+    for pid in range(8, 16):
+        want = ref.lowered_plan.execute(
+            pages_from_partition(inflate_partition(src.partition(pid)), spec)
+        )
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(outB[pid][k]), np.asarray(want[k]))

@@ -16,6 +16,7 @@ def test_dryrun_cli_reduced(mesh):
     with tempfile.TemporaryDirectory() as d:
         out = os.path.join(d, "dryrun.jsonl")
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"  # fake CPU devices; never the chip
         env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
         proc = subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun",

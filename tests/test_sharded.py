@@ -109,8 +109,7 @@ s1, m1 = cstep(state, batch)
 s2, m2 = cstep(s1, batch)
 assert float(m2["loss"]) < float(m1["loss"])
 txt = cstep.lower(state, batch).compile().as_text()
-# the cross-pod hop must carry int8: all-gather on current jax, the compat
-# psum-slot emulation on old jax (either way the collective operand is s8)
+# the cross-pod hop must carry int8: the all-gather's operand is s8
 n_s8 = sum(1 for l in txt.splitlines()
            if "s8" in l and ("all-gather" in l or "all-reduce" in l))
 assert n_s8 > 0
