@@ -27,7 +27,7 @@ from repro.data.synth import RMDataConfig, SyntheticRecSysSource
 from repro.distributed.sharding import ShardingRules
 from repro.models.recsys import RecSysConfig, init_params, loss_fn
 from repro.train import CheckpointManager, adamw, make_train_step, warmup_cosine
-from repro.common import param_count
+from repro.common.util import param_count
 
 
 def main() -> None:

@@ -1,15 +1,5 @@
-from repro.common.util import (
-    Timer,
-    bytes_of_tree,
-    human_bytes,
-    human_flops,
-    param_count,
-)
+"""Small shared utilities (``util``) and the host span names (``trace``).
 
-__all__ = [
-    "Timer",
-    "bytes_of_tree",
-    "human_bytes",
-    "human_flops",
-    "param_count",
-]
+Import the submodules directly: the package itself loads nothing, so the
+data layer can use ``trace`` without importing JAX.
+"""

@@ -1,11 +1,9 @@
-"""Small shared utilities: timing, tree accounting, formatting."""
+"""Small shared utilities: compile cache, tree accounting, formatting."""
 
 from __future__ import annotations
 
 import os
-import time
-from contextlib import contextmanager
-from typing import Any, Iterator
+from typing import Any
 
 import jax
 import numpy as np
@@ -30,32 +28,6 @@ def enable_compile_cache() -> str:
     path = os.path.join(REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path
-
-
-class Timer:
-    """Wall-clock timer usable as context manager or start/stop pairs."""
-
-    def __init__(self) -> None:
-        self.elapsed = 0.0
-        self._start: float | None = None
-
-    def __enter__(self) -> "Timer":
-        self._start = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc: Any) -> None:
-        assert self._start is not None
-        self.elapsed += time.perf_counter() - self._start
-        self._start = None
-
-
-@contextmanager
-def timed(label: str, sink: dict | None = None) -> Iterator[None]:
-    t0 = time.perf_counter()
-    yield
-    dt = time.perf_counter() - t0
-    if sink is not None:
-        sink[label] = sink.get(label, 0.0) + dt
 
 
 def bytes_of_tree(tree: Any) -> int:

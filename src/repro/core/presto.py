@@ -41,9 +41,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.common import trace
 from repro.core.execcache import EXECUTABLES, ExecKey, mesh_key
 from repro.core.opgraph import (
     FAMILIES,
@@ -405,9 +407,10 @@ class PreStoEngine:
         stored form; inflation is host-side decompression after the read).
         """
         part = store.read(pid)
-        if self.mesh is not None:
-            part = inflate_partition(part)
-        return pages_from_partition(part, self.spec)
+        with TraceAnnotation(trace.PAGE_BUILD, pid=pid):
+            if self.mesh is not None:
+                part = inflate_partition(part)
+            return pages_from_partition(part, self.spec)
 
     def stage_megabatch(
         self, store: PartitionedStore, pids: Sequence[int]

@@ -79,7 +79,9 @@ from typing import Any, Callable, Dict, Iterable, Iterator, List, Optional, Tupl
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.common import trace
 from repro.core.autotune import DEFAULT_AUTOTUNE_KMAX, MegabatchTuner
 from repro.core.costmodel import ContentionAwareCostModel, PartitionCosts
 from repro.core.ctrlplane import EventLog, SessionCheckpoint, SessionError
@@ -269,6 +271,10 @@ class SessionStats:
     cancelled: bool = False
     done: bool = False
     host_fallbacks: int = 0  # fresh claims routed off their owning device
+    # -- pool pipeline observability --
+    launches: int = 0  # chunks dispatched; produced / launches is the mean K
+    # fresh claims refused because queue_depth batches were undelivered
+    backpressured: int = 0
     # -- storage fault domain observability --
     retries: int = 0  # claims re-issued after a retryable I/O fault
     failovers: int = 0  # claims re-routed off an offline device's replica path
@@ -492,6 +498,7 @@ class Session:
         # guarded by self._slock:
         self._slock = threading.Lock()
         self._produced = 0
+        self._launches = 0
         self._handed = 0  # futures taken off the delivery queue (any stream)
         self._delivered = 0
         self._delivered_pids: List[int] = []  # the checkpoint frontier
@@ -604,21 +611,23 @@ class Session:
     def __iter__(self) -> Iterator[Tuple[int, Any]]:
         while True:
             t0 = time.perf_counter()
-            fut = self._next_future()
-            if fut is None:
-                return
-            while True:
-                if self.cancelled:
+            with TraceAnnotation(trace.CONSUMER_WAIT) as span:
+                fut = self._next_future()
+                if fut is None:
                     return
-                try:
-                    pid, batch = fut.result(timeout=0.25)
-                    break
-                except FutureTimeoutError:
-                    self._check_liveness()
-            # pacing signal only once the batch is resolved and in the
-            # consumer's hands: at most queue_depth batches sit materialized
-            self._queue.mark_delivered()
-            self._service._wake()
+                while True:
+                    if self.cancelled:
+                        return
+                    try:
+                        pid, batch = fut.result(timeout=0.25)
+                        break
+                    except FutureTimeoutError:
+                        self._check_liveness()
+                span.set_metadata(pid=pid)
+                # pacing signal only once the batch is resolved and in the
+                # consumer's hands: at most queue_depth batches sit materialized
+                self._queue.mark_delivered()
+                self._service._wake()
             with self._slock:
                 self._wait_time += time.perf_counter() - t0
                 self._delivered += 1
@@ -679,6 +688,8 @@ class Session:
                 cancelled=self.cancelled,
                 done=self._delivered >= self.total,
                 host_fallbacks=self._queue.host_fallbacks,
+                launches=self._launches,
+                backpressured=self._queue.backpressured,
                 retries=self._retries,
                 failovers=self._failovers,
                 quarantined=self._quarantined,
@@ -864,11 +875,14 @@ class Session:
         so straggler twins would fail identically).
         """
         claims = [claim]
-        for _ in range(self._current_k() - 1):
-            extra = self._queue.claim(prefer_device=prefer)
-            if extra is None:
-                break
-            claims.append(extra)
+        k = self._current_k()
+        if k > 1:
+            with TraceAnnotation(trace.CLAIM):
+                for _ in range(k - 1):
+                    extra = self._queue.claim(prefer_device=prefer)
+                    if extra is None:
+                        break
+                    claims.append(extra)
         if not self._stageable:
             return _Chunk(self, claims, None)
         t0 = time.perf_counter()
@@ -896,7 +910,8 @@ class Session:
                 kept.append((pid, f, r))
             if not kept:
                 return None
-            pages = stack_pages(per)
+            with TraceAnnotation(trace.STACK, k=len(kept), pid=kept[0][0]):
+                pages = stack_pages(per)
         except BaseException as exc:  # noqa: BLE001 — consumer re-raises
             for pid, _f, _r in kept or claims:
                 self._on_produce_error(pid, exc)
@@ -1046,21 +1061,25 @@ class Session:
             self._route_begin(pid, route) for pid, _f, route in chunk.claims
         ]
         chunk.t0 = time.perf_counter()
+        with self._slock:
+            self._launches += 1
         try:
             if chunk.pages is None:
                 ((pid, _f, _r),) = chunk.claims
                 return "value", [self._produce_fn(pid)]
             engine = self.engine
+            meta = dict(k=len(chunk.claims), pid=chunk.claims[0][0])
+            pages = chunk.pages
             if len(chunk.claims) == 1:
                 # reuse the solo executable (one compile shared with every
                 # produce_batch of this signature, process-wide)
-                pages = {k: v[0] for k, v in chunk.pages.items()}
-                return "async", engine.jit_preprocess_cached()(
-                    engine._put_pages(pages)
-                )
-            return "async", engine.jit_preprocess_megabatch_cached()(
-                engine._put_pages(chunk.pages)
-            )
+                pages = {k: v[0] for k, v in pages.items()}
+            with TraceAnnotation(trace.PUT, **meta):
+                pages = engine._put_pages(pages)
+            with TraceAnnotation(trace.DISPATCH, **meta):
+                if len(chunk.claims) == 1:
+                    return "async", engine.jit_preprocess_cached()(pages)
+                return "async", engine.jit_preprocess_megabatch_cached()(pages)
         except BaseException as exc:  # noqa: BLE001 — consumer re-raises
             return "error", exc
 
@@ -1083,7 +1102,10 @@ class Session:
                 return
             if kind == "async":
                 try:
-                    jax.block_until_ready(payload)
+                    with TraceAnnotation(
+                        trace.FINISH, k=len(chunk.claims), pid=chunk.claims[0][0]
+                    ):
+                        jax.block_until_ready(payload)
                 except BaseException as exc:  # noqa: BLE001
                     for pid, _f, _r in chunk.claims:
                         self._on_produce_error(pid, exc)
@@ -1103,8 +1125,11 @@ class Session:
                 # hidden behind the next chunk's staging
                 if self._tuner.record(len(chunk.claims), dt):
                     self._on_tuned_k_changed()
-            for (pid, _f, route), batch in zip(chunk.claims, batches):
-                self._on_produced(pid, batch, share, route)
+            with TraceAnnotation(
+                trace.DELIVER, k=len(chunk.claims), pid=chunk.claims[0][0]
+            ):
+                for (pid, _f, route), batch in zip(chunk.claims, batches):
+                    self._on_produced(pid, batch, share, route)
         finally:
             for dev in chunk.devs:
                 self._route_end(dev)
@@ -2041,12 +2066,13 @@ class PreprocessingService:
             if staged is None:
                 if self._stop.is_set() or w.retired.is_set():
                     break
-                task = self._next_task(wdev)
+                with TraceAnnotation(trace.CLAIM):
+                    task = self._next_task(wdev)
                 if task is None:
                     self._prune()
                     # idle: sleep until nudged (submit / freed slot / pacing
                     # signal); the timeout keeps straggler scans alive
-                    with self._wake_cv:
+                    with TraceAnnotation(trace.IDLE), self._wake_cv:
                         self._wake_cv.wait(timeout=0.05)
                     continue
                 staged = self._stage_task(task[0], task[1], wdev)
@@ -2066,7 +2092,8 @@ class PreprocessingService:
                     # double buffering: the next chunk's partition read and
                     # numpy page-build overlap the in-flight kernel
                     t_ov = time.perf_counter()
-                    nxt = self._next_task(wdev, stageable_only=True)
+                    with TraceAnnotation(trace.CLAIM):
+                        nxt = self._next_task(wdev, stageable_only=True)
                     if nxt is not None:
                         staged = self._stage_task(nxt[0], nxt[1], wdev)
                     # deep lookahead: with the next chunk staged, walk the

@@ -45,6 +45,7 @@ from typing import Dict, List, Mapping
 
 import numpy as np
 
+from repro.common import trace
 from repro.data import encoding as enc
 
 _MAGIC = b"RPRESTO1"
@@ -463,7 +464,32 @@ def write_partition(path: str, part: Partition) -> None:
         f.write(body)
 
 
-def read_partition(path: str) -> Partition:
+def read_partition(path: str, *, spans: bool = True) -> Partition:
+    """Read one partition file: file I/O, the body's checksum, then the
+    page-table decode, each under its own host span (``repro.common.trace``).
+
+    ``spans=False`` reads without them, for a derivation of metadata that is
+    not a data-path read and may run inside another span.
+    """
+    if not spans:
+        pid, schema, pages, checksum, body = _read_file(path)
+        _verify(path, checksum, body)
+        return _decode(path, pid, schema, pages, body)
+    # JAX is loaded only to read: writers (encoding pools) never import it
+    from jax.profiler import TraceAnnotation
+
+    with TraceAnnotation(trace.READ_IO) as span:
+        pid, schema, pages, checksum, body = _read_file(path)
+        span.set_metadata(pid=pid)
+    with TraceAnnotation(trace.READ_VERIFY, pid=pid):
+        _verify(path, checksum, body)
+    with TraceAnnotation(trace.READ_DECODE, pid=pid):
+        return _decode(path, pid, schema, pages, body)
+
+
+def _read_file(path: str):
+    """(partition id, schema, page table, body checksum or None, body) of a
+    partition file, its header checked."""
     with open(path, "rb") as f:
         magic = f.read(8)
         if magic != _MAGIC:
@@ -486,8 +512,10 @@ def read_partition(path: str) -> Partition:
             partition_id = header["partition_id"]
         except (ValueError, KeyError, TypeError, AssertionError) as e:
             raise CorruptPartitionFile(f"{path}: corrupt header: {e}") from e
-        body = f.read()
-    want_ck = header.get("checksum")
+        return partition_id, schema, pages, header.get("checksum"), f.read()
+
+
+def _verify(path: str, want_ck: str | None, body: bytes) -> None:
     if want_ck is not None:
         got_ck = hashlib.sha256(body).hexdigest()[:16]
         if got_ck != want_ck:
@@ -495,6 +523,11 @@ def read_partition(path: str) -> Partition:
                 f"{path}: payload checksum mismatch "
                 f"(stored {want_ck}, computed {got_ck})"
             )
+
+
+def _decode(
+    path: str, partition_id: int, schema: PartitionSchema, pages: list, body: bytes
+) -> Partition:
     cols: Dict[str, EncodedColumn] = {}
     cschemas = {c.name: c for c in schema.columns}
     off = 0

@@ -396,6 +396,8 @@ class SessionQueue:
         self.on_settled = on_settled
         self.on_offload = on_offload
         self.host_fallbacks = 0  # fresh claims routed off their device
+        # fresh claims refused at depth while fresh work was still pending
+        self.backpressured = 0
 
     def claim(
         self, prefer_device: Optional[int] = None
@@ -425,6 +427,8 @@ class SessionQueue:
                     fallback_ok=self.fallback_ok,
                 )
                 if pid is None:
+                    if backpressured and self.work.peek_ahead(1):
+                        self.backpressured += 1
                     return None
                 fut = self._futures.get(pid)
                 fresh = fut is None

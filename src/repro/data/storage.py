@@ -732,6 +732,7 @@ class PartitionedStore:
         for pid in partition_ids:
             path = self._path(pid)
             if not os.path.exists(path):
+                os.makedirs(os.path.dirname(path), exist_ok=True)
                 write_partition(path, self.source.partition(pid))
 
     def read(self, partition_id: int) -> Partition:
@@ -772,9 +773,10 @@ class PartitionedStore:
     def _read_raw(self, partition_id: int) -> Partition:
         """The unverified read: disk file wins, else the synthetic source."""
         if self.root is not None:
-            path = self._path(partition_id)
-            if os.path.exists(path):
-                return read_partition(path)
+            try:
+                return read_partition(self._path(partition_id))
+            except FileNotFoundError:
+                pass
         assert self.source is not None, "no disk file and no synthetic source"
         return self.source.partition(partition_id)
 
@@ -926,7 +928,7 @@ class PartitionedStore:
                 hit = self._blockfp_cache.get(partition_id)
             if hit is not None and hit[0] == sig:
                 return hit[1]
-            part = read_partition(path)  # metadata derivation: not a
+            part = read_partition(path, spans=False)  # metadata derivation: not a
             # modeled data-path read, like partition_fingerprint's file hash
             fps = columnar.block_fingerprints(part)
             meta = (
@@ -961,9 +963,7 @@ class PartitionedStore:
         # deviceNN/ prefix models per-device directories of the storage array
         assert self.root is not None
         dev = self.owner_of(pid)
-        ddir = os.path.join(self.root, f"device{dev:03d}")
-        os.makedirs(ddir, exist_ok=True)
-        return os.path.join(ddir, f"part{pid:06d}.rp")
+        return os.path.join(self.root, f"device{dev:03d}", f"part{pid:06d}.rp")
 
 
 class CacheSpillStore:

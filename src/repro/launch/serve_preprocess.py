@@ -6,7 +6,11 @@ its own partition range, placement, and (optional) QoS target; every tenant
 is drained by its own consumer thread that simulates a trainer (a fixed
 per-batch train time).  Prints the paper's Fig. 3 accounting per job —
 utilization, starvation, straggler re-issues, feature-cache hits — plus the
-pool's unit shares.
+pool's unit shares, each job's chunk launches (``launch``; batches over
+launches is its mean megabatch K) and the fresh claims its backpressure
+refused (``bkpres``).  Run under ``jax.profiler.trace`` the same
+jobs leave the pool workers' ``presto.*`` spans (``repro.common.trace``)
+beside the device's ops.
 
 With ``--cache`` the pool carries a shared content-addressed feature cache
 (``core.featcache``): tenants of the same RM generate identical partition
@@ -500,8 +504,8 @@ def main(argv=None) -> None:
 
     print(f"\n{'job':<12} {'batches':>7} {'rows/s':>9} {'util':>6} "
           f"{'starve':>7} {'reissue':>7} {'dupes':>6} {'hits':>5} "
-          f"{'blk':>7} {'fallbk':>6} {'tunedK':>6} {'staged':>8} "
-          f"{'prewrm':>6} {'share/demand':>13}")
+          f"{'blk':>7} {'fallbk':>6} {'tunedK':>6} {'launch':>6} "
+          f"{'bkpres':>6} {'staged':>8} {'prewrm':>6} {'share/demand':>13}")
     for job in jobspecs:
         st = final_sessions[job.name].stats()
         result = results[job.name]
@@ -520,6 +524,7 @@ def main(argv=None) -> None:
               f"{util:>6.2f} {st.starvation:>7.2f} {st.reissues:>7} "
               f"{st.duplicates_dropped:>6} {st.cache_hits:>5} "
               f"{blk:>7} {st.host_fallbacks:>6} {st.tuned_k:>6} "
+              f"{st.launches:>6} {st.backpressured:>6} "
               f"{staged:>8} {st.prewarm_hits:>6} "
               f"{st.share:>7}/{st.effective_demand_units}")
     total_rows = sum(s.stats().rows_delivered for s in final_sessions.values())

@@ -15,6 +15,7 @@ every test file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,10 +140,19 @@ def test_sigridhash_compiles(one_chip):
     )
 
 
+# kernel instruction names the benchmark's rooflines find the kernels by
+KERNEL_OPS = ("fused_dense_pallas", "fused_sparse_pallas", "fused_gen_pallas")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.-]+?)(?:\.\d+)? = ", re.M)
+
+
 def test_rm2_presto_program_compiles(one_chip):
     """The whole K=1 produce program of the default JobSpec (presto, fused
-    kernels), as ``PreStoEngine.jit_preprocess_cached`` would compile it."""
+    kernels), as ``PreStoEngine.jit_preprocess_cached`` would compile it.
+    Each fused kernel keeps its instruction name, which a device trace
+    reports as the op's name."""
     engine = PreStoEngine(_spec("rm2"), placement="presto", interpret=False)
     compiled = _compile(engine.preprocess_local, _pages("rm2", one_chip))
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
+    names = set(_INSTRUCTION.findall(compiled.as_text()))
+    assert set(KERNEL_OPS) <= names, set(KERNEL_OPS) - names
