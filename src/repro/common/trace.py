@@ -23,7 +23,8 @@ CLAIM = "presto.claim"
 READ_IO = "presto.read.io"
 READ_VERIFY = "presto.read.verify"
 READ_DECODE = "presto.read.decode"
-# pool worker: ``pages_from_partition`` (and inflation, where a mesh needs it)
+# pool worker: ``pages_from_partition`` (and inflation, where a mesh needs it;
+# ``pack_pages`` where there is none)
 PAGE_BUILD = "presto.page_build"
 # pool worker: ``stack_pages`` of a chunk (a view at K=1, a copy at K>1)
 STACK = "presto.stack"

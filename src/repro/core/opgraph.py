@@ -204,16 +204,71 @@ def build_transform_graph(spec: TransformSpec) -> OpGraph:
     )
 
 
+LANES = 128  # a TPU vector register's lane count
+
+
+def lane_rows(g: int, w: int) -> int:
+    """Whole 128-lane rows holding one feature's ``g*w`` page words."""
+    return -(-g * w // LANES)
+
+
+def page_geometry(cfg, rows: int, unique_rows: int) -> Dict[str, Tuple[int, int, int]]:
+    """The kernels' ``(features, row_groups, words)`` shape of each grouped
+    page array of a partition of `rows` (`unique_rows` sparse blocks), in
+    the order a mesh-less engine packs their lane rows
+    (``preprocess.pack_pages``)."""
+    return {
+        "dense_words": (cfg.n_dense, rows // 4, 4),
+        "sparse_words": (
+            cfg.n_sparse, unique_rows * cfg.max_sparse_len // 32, cfg.id_width
+        ),
+        "length_words": (cfg.n_sparse, unique_rows // 32, cfg.len_width),
+    }
+
+
+def kernel_pages(pages: Dict[str, jax.Array], spec: TransformSpec) -> Dict[str, jax.Array]:
+    """View staged page arrays at the kernels' ``(F, G, w)`` shapes (traceable).
+
+    Mesh-less engines stage the grouped page arrays packed into one
+    lane-dense ``page_rows`` buffer of 128-lane rows
+    (``preprocess.pack_pages``), so the host-to-device put is one plain
+    copy; this cuts each family's region back out on the device, takes each
+    feature's first ``G*w`` words (dropping the zero tail of a partial lane
+    row) and reshapes them.  Pages without ``page_rows`` — meshed engines
+    stage the kernels' shapes — pass through.  Labels ``(..., rows)`` give
+    the geometry, and a leading megabatch axis is kept.  Dedup pages
+    (carrying ``sparse_refs``) hold ``rows / dup_factor`` sparse blocks.
+    """
+    out = dict(pages)
+    packed = out.pop("page_rows", None)
+    if packed is None:
+        return out
+    labels = pages["label_words"]
+    lead, rows = tuple(labels.shape[:-1]), labels.shape[-1]
+    d = spec.cfg.dup_factor if "sparse_refs" in pages else 1
+    start = 0
+    for name, (f, g, w) in page_geometry(spec.cfg, rows, rows // d).items():
+        r = lane_rows(g, w)
+        region = packed[..., start : start + f * r, :]
+        start += f * r
+        out[name] = region.reshape(*lead, f, r * LANES)[..., : g * w].reshape(
+            *lead, f, g, w
+        )
+    assert start == packed.shape[-2], (start, packed.shape)
+    return out
+
+
 def prepare_env(pages: Dict[str, jax.Array], spec: TransformSpec) -> Dict[str, Any]:
     """Bind graph page inputs from the staged page arrays.
 
+    The page arrays enter at the kernels' shapes (``kernel_pages``).
     ``gen_words`` (the generated features' source planes) is a static gather
     of dense pages — computed here so the gen family never depends on the
     dense family's placement.
     """
-    env = dict(pages)
+    env = kernel_pages(pages, spec)
     src = jnp.asarray(np.asarray(spec.generated_source, np.int32))
-    env["gen_words"] = jnp.take(pages["dense_words"], src, axis=0)
+    env["gen_words"] = jnp.take(env["dense_words"], src, axis=0)
     return env
 
 
@@ -264,14 +319,15 @@ def family_page_bytes(spec: TransformSpec, rows: int) -> Dict[str, int]:
     """
     cfg = spec.cfg
     d = max(int(getattr(cfg, "dup_factor", 1)), 1)
-    u = rows // d
+    words = {
+        name: f * g * w
+        for name, (f, g, w) in page_geometry(cfg, rows, rows // d).items()
+    }
     return {
-        "dense": cfg.n_dense * rows * 4,  # bytesplit: 4 plane bytes / value
-        "sparse": cfg.n_sparse * (u * cfg.max_sparse_len // 32)
-        * cfg.id_width * 4
-        + (rows * 4 if d > 1 else 0),
+        "dense": words["dense_words"] * 4,  # bytesplit: 4 plane bytes / value
+        "sparse": words["sparse_words"] * 4 + (rows * 4 if d > 1 else 0),
         "gen": cfg.n_generated * rows * 4,  # sourced dense planes
-        "lengths": cfg.n_sparse * (u // 32) * cfg.len_width * 4,
+        "lengths": words["length_words"] * 4,
         "labels": rows * 4,
     }
 

@@ -27,9 +27,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.opgraph import (
+    LANES,
     LoweredPlan,
     build_transform_graph,
+    kernel_pages,
+    lane_rows,
     lower,
+    page_geometry,
     prepare_env,
     resolve_placements,
 )
@@ -41,11 +45,22 @@ MiniBatch = Dict[str, jax.Array]
 
 
 # ---------------------------------------------------------------------------
-# Host-side page staging: Partition (numpy, flat pages) -> kernel layout
+# Host-side page staging: Partition (numpy, flat pages) -> staged layout
 
 
-def pages_from_partition(part: Partition, spec: TransformSpec) -> Dict[str, np.ndarray]:
-    """Stack per-column pages into the grouped arrays the kernels consume.
+def pack_pages(part: Partition, spec: TransformSpec) -> Dict[str, np.ndarray]:
+    """Lay one partition's pages out as a mesh-less engine stages and puts them.
+
+    Every ``jax.device_put`` array costs the host a fixed time (and, beside
+    a second worker, a wait for the interpreter lock) however few its
+    bytes, so the grouped pages cross as ONE ``page_rows`` array: each
+    feature's regrouped words (the kernels' ``(G, w)`` layout, row-major)
+    as whole 128-lane rows, the tail of a partial row zero, families in
+    ``opgraph.page_geometry`` order.  A row-major ``(R, 128)`` array is also
+    the TPU's own layout, byte for byte numpy's C order, so the put is a
+    plain copy with no relayout.  Labels stay flat ``(rows,)``.  The
+    compiled program cuts the kernels' arrays back out
+    (``opgraph.kernel_pages``).
 
     Dedup partitions (``schema.dup_factor > 1``) stage their sparse/length
     pages at UNIQUE-block geometry — each shared block's encoded words enter
@@ -56,22 +71,31 @@ def pages_from_partition(part: Partition, spec: TransformSpec) -> Dict[str, np.n
     cfg = spec.cfg
     rows = part.schema.rows
     u = part.schema.unique_rows  # == rows for classic partitions
-    dense = []
-    for i in range(cfg.n_dense):
-        col = part.columns[f"d{i}"]
-        dense.append(K.regroup_bytesplit(col.pages["data"], rows))
-    sparse, lengths = [], []
-    n_vals = u * cfg.max_sparse_len
-    for i in range(cfg.n_sparse):
-        col = part.columns[f"s{i}"]
-        sparse.append(K.regroup_bitpack(col.pages["values"], n_vals, cfg.id_width))
-        lengths.append(K.regroup_bitpack(col.pages["lengths"], u, cfg.len_width))
-    label_words = part.columns["label"].pages["data"][:rows]
+    columns = {
+        "dense_words": lambda i: K.regroup_bytesplit(
+            part.columns[f"d{i}"].pages["data"], rows
+        ),
+        "sparse_words": lambda i: K.regroup_bitpack(
+            part.columns[f"s{i}"].pages["values"], u * cfg.max_sparse_len,
+            cfg.id_width,
+        ),
+        "length_words": lambda i: K.regroup_bitpack(
+            part.columns[f"s{i}"].pages["lengths"], u, cfg.len_width
+        ),
+    }
+    geometry = page_geometry(cfg, rows, u)
+    n = sum(f * lane_rows(g, w) for f, g, w in geometry.values())
+    page_rows = np.zeros((n, LANES), np.uint32)
+    words = page_rows.reshape(-1)
+    start = 0
+    for name, (f, g, w) in geometry.items():
+        stride = lane_rows(g, w) * LANES
+        for i in range(f):
+            words[start : start + g * w] = columns[name](i).reshape(-1)
+            start += stride
     pages = {
-        "dense_words": np.stack(dense),  # (n_dense, rows/4, 4) u32
-        "sparse_words": np.stack(sparse),  # (n_sparse, u*L/32, w) u32
-        "length_words": np.stack(lengths),  # (n_sparse, u/32, lw) u32
-        "label_words": label_words,  # (rows,) u32
+        "page_rows": page_rows,  # (R, 128) u32
+        "label_words": part.columns["label"].pages["data"][:rows],  # (rows,) u32
     }
     refs = partition_refs(part)
     if refs is not None:
@@ -79,10 +103,20 @@ def pages_from_partition(part: Partition, spec: TransformSpec) -> Dict[str, np.n
     return pages
 
 
+def pages_from_partition(part: Partition, spec: TransformSpec) -> Dict[str, np.ndarray]:
+    """One partition's pages at the kernels' shapes, as meshed engines stage
+    them: ``dense_words`` ``(n_dense, rows/4, 4)``, ``sparse_words``
+    ``(n_sparse, u*L/32, w)``, ``length_words`` ``(n_sparse, u/32, lw)``
+    u32, ``label_words`` ``(rows,)`` u32 (and ``sparse_refs`` where the
+    partition dedups) — ``pack_pages`` seen through ``opgraph.kernel_pages``.
+    """
+    return kernel_pages(pack_pages(part, spec), spec)
+
+
 def stack_pages(pages_list) -> Dict[str, np.ndarray]:
     """Stack K partitions' staged pages into one leading-axis megabatch.
 
-    Input: K dicts from ``pages_from_partition`` (equal shapes — megabatches
+    Input: K dicts from ``stage_partition`` (equal shapes — megabatches
     require uniform partition geometry, which the partitioned stores
     guarantee).  Output: one dict whose every array gains a leading K axis,
     the input of ``PreStoEngine.preprocess_megabatch``.
@@ -95,17 +129,21 @@ def stack_pages(pages_list) -> Dict[str, np.ndarray]:
     }
 
 
-def flatten_megabatch(stacked: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+def flatten_megabatch(
+    stacked: Dict[str, jax.Array], spec: TransformSpec
+) -> Dict[str, jax.Array]:
     """Fold the leading megabatch axis into the row-group axis (traceable).
 
-    Every page array is grouped ``(features, row_groups, words)`` with the
-    feature axis leading (labels are flat ``(rows,)``), and every operator in
-    the standard Transform is row-local — so a K-partition megabatch is
-    exactly a single partition with K x the rows.  ``(K, F, G, w)`` becomes
-    ``(F, K*G, w)`` (partition-major row order) and ``(K, R)`` becomes
-    ``(K*R,)``; the resulting mini-batch splits back per partition along its
-    leading row axis.
+    Every page array, viewed at its kernel shape (``kernel_pages``), is
+    grouped ``(features, row_groups, words)`` with the feature axis leading
+    (labels are flat ``(rows,)``), and every operator in the standard
+    Transform is row-local — so a K-partition megabatch is exactly a single
+    partition with K x the rows.  ``(K, F, G, w)`` becomes ``(F, K*G, w)``
+    (partition-major row order) and ``(K, R)`` becomes ``(K*R,)``; the
+    resulting mini-batch splits back per partition along its leading row
+    axis.
     """
+    stacked = kernel_pages(stacked, spec)
     out: Dict[str, jax.Array] = {}
     for name, v in stacked.items():
         if name == "sparse_refs":
@@ -135,25 +173,37 @@ def megabatch_pages_shape_dtypes(
 
 
 def pages_shape_dtypes(spec: TransformSpec, rows: int) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct stand-ins for the page arrays (dry-run inputs).
+    """ShapeDtypeStruct stand-ins for the staged page arrays (dry-run inputs):
+    packed (``pack_pages``), as mesh-less engines stage them."""
+    cfg = spec.cfg
+    geometry = page_geometry(cfg, rows, rows // getattr(cfg, "dup_factor", 1))
+    out = {
+        name: s
+        for name, s in kernel_pages_shape_dtypes(spec, rows).items()
+        if name not in geometry
+    }
+    n = sum(f * lane_rows(g, w) for f, g, w in geometry.values())
+    out["page_rows"] = jax.ShapeDtypeStruct((n, LANES), jnp.uint32)
+    return out
+
+
+def kernel_pages_shape_dtypes(
+    spec: TransformSpec, rows: int
+) -> Dict[str, jax.ShapeDtypeStruct]:
+    """ShapeDtypeStruct stand-ins for the page arrays at the kernels' shapes,
+    as ``pages_from_partition`` builds them and meshed engines stage them.
 
     Sparse/length pages live at unique-block geometry when the dataset
     dedups (``cfg.dup_factor > 1``), matching ``pages_from_partition``.
     """
     cfg = spec.cfg
     d = getattr(cfg, "dup_factor", 1)
-    u = rows // d
     u32 = jnp.uint32
     out = {
-        "dense_words": jax.ShapeDtypeStruct((cfg.n_dense, rows // 4, 4), u32),
-        "sparse_words": jax.ShapeDtypeStruct(
-            (cfg.n_sparse, u * cfg.max_sparse_len // 32, cfg.id_width), u32
-        ),
-        "length_words": jax.ShapeDtypeStruct(
-            (cfg.n_sparse, u // 32, cfg.len_width), u32
-        ),
-        "label_words": jax.ShapeDtypeStruct((rows,), u32),
+        name: jax.ShapeDtypeStruct(shape, u32)
+        for name, shape in page_geometry(cfg, rows, rows // d).items()
     }
+    out["label_words"] = jax.ShapeDtypeStruct((rows,), u32)
     if d > 1:
         out["sparse_refs"] = jax.ShapeDtypeStruct((rows,), jnp.int32)
     return out
@@ -175,12 +225,12 @@ def execute_plan(plan: LoweredPlan, pages: Dict[str, jax.Array]) -> MiniBatch:
     identical to expand-then-transform: the undeduped result, for fused,
     unfused and hybrid lowerings alike.
     """
-    if "sparse_refs" not in pages:
-        return plan.execute(pages)
-    pages = dict(pages)
-    refs = jnp.asarray(pages.pop("sparse_refs"))
-    cfg = plan.spec.cfg
     env = prepare_env(pages, plan.spec)
+    refs = env.pop("sparse_refs", None)
+    if refs is None:
+        return plan.execute_env(env)
+    refs = jnp.asarray(refs)
+    cfg = plan.spec.cfg
     for st in plan.stages:
         if st.name == "form_batch":
             sh = env["sparse_hashed"]  # (n_sparse, u*L) at unique geometry

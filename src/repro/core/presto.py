@@ -63,6 +63,8 @@ from repro.core.preprocess import (
     MiniBatch,
     execute_plan,
     flatten_megabatch,
+    kernel_pages_shape_dtypes,
+    pack_pages,
     pages_from_partition,
     pages_shape_dtypes,
     stack_pages,
@@ -370,7 +372,7 @@ class PreStoEngine:
             "lowered plan has a non-row-local stage; megabatch would not be "
             "bitwise identical to solo runs"
         )
-        mb = self.preprocess_local(flatten_megabatch(stacked))
+        mb = self.preprocess_local(flatten_megabatch(stacked, self.spec))
         if k == 1:
             return (mb,)
         split = {key: jnp.split(v, k, axis=0) for key, v in mb.items()}
@@ -399,18 +401,23 @@ class PreStoEngine:
     def stage_partition(self, store: PartitionedStore, pid: int) -> Dict[str, np.ndarray]:
         """Extract(Read): fetch + lay out one partition's pages (host side).
 
-        Meshed engines shard pages along the row-group axis
-        (``pages_pspec``), which a dedup partition's unique-geometry pages
-        would break — those inflate (``columnar.inflate_partition``, bitwise
-        faithful) to the classic per-sample layout first.  The I/O ledger
-        still charges only the UNIQUE bytes (``store.read`` streams the
-        stored form; inflation is host-side decompression after the read).
+        Mesh-less engines pack the grouped page arrays into one lane-dense
+        buffer (``preprocess.pack_pages``), so the host-to-device put is
+        one plain copy; the compiled program cuts the kernels' shapes back
+        out.  Meshed engines shard pages along the row-group axis
+        (``pages_pspec``), which must stay aligned with rows, so they keep
+        the kernels' ``(F, G, w)`` shapes — and a dedup partition's
+        unique-geometry pages would break that row alignment, so those
+        inflate (``columnar.inflate_partition``, bitwise faithful) to the
+        classic per-sample layout first.  The I/O ledger still charges only
+        the UNIQUE bytes (``store.read`` streams the stored form; inflation
+        is host-side decompression after the read).
         """
         part = store.read(pid)
         with TraceAnnotation(trace.PAGE_BUILD, pid=pid):
             if self.mesh is not None:
-                part = inflate_partition(part)
-            return pages_from_partition(part, self.spec)
+                return pages_from_partition(inflate_partition(part), self.spec)
+            return pack_pages(part, self.spec)
 
     def stage_megabatch(
         self, store: PartitionedStore, pids: Sequence[int]
@@ -545,7 +552,10 @@ class PreStoEngine:
                     yield pid, mb
 
     def pages_struct(self, rows: int) -> Dict[str, jax.ShapeDtypeStruct]:
-        return pages_shape_dtypes(self.spec, rows)
+        """Stand-ins for the pages ``stage_partition`` returns."""
+        if self.mesh is None:
+            return pages_shape_dtypes(self.spec, rows)
+        return kernel_pages_shape_dtypes(self.spec, rows)
 
     # -- block-granularity cache hooks (dedup datasets) -------------------------
     #
@@ -611,16 +621,12 @@ class PreStoEngine:
         """Full batch from cached sparse blocks + the rest program.
 
         ``pages`` is dedup-staged (``stage_partition``) output; only its
-        dense/label pages feed the compiled rest program — the sparse pages'
-        decode+hash work is what the block cache saved.  Bitwise identical
-        to a cold produce of the same partition.
+        dense/label pages feed the compiled rest program's kernels — the
+        sparse pages' decode+hash work is what the block cache saved.
+        Bitwise identical to a cold produce of the same partition.
         """
         refs = np.asarray(pages["sparse_refs"], dtype=np.int64)
-        rest_pages = {
-            "dense_words": pages["dense_words"],
-            "label_words": pages["label_words"],
-        }
-        batch = dict(self.jit_preprocess_rest_cached()(rest_pages))
+        batch = dict(self.jit_preprocess_rest_cached()(pages))
         batch["multi_hot_ids"] = jnp.asarray(np.asarray(block_ids)[refs])
         batch["lengths"] = jnp.asarray(np.asarray(block_lens)[refs])
         return batch

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.core.preprocess import (
+    pack_pages,
     pages_from_partition,
     pages_shape_dtypes,
     preprocess_pages,
@@ -72,7 +73,7 @@ def test_stage_functions_compose(small_rm):
 
 def test_pages_shape_dtypes_match(small_rm):
     src, spec = small_rm
-    pages = _pages(src, spec)
+    pages = pack_pages(src.partition(0), spec)
     struct = pages_shape_dtypes(spec, 256)
     assert set(struct) == set(pages)
     for k in pages:

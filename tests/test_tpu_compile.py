@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.preprocess import pages_shape_dtypes
+from repro.core.preprocess import kernel_pages_shape_dtypes, pages_shape_dtypes
 from repro.core.presto import PreStoEngine
 from repro.core.spec import TransformSpec
 from repro.data.synth import RM_CONFIGS, SyntheticRecSysSource
@@ -66,6 +66,15 @@ def _spec(rm: str) -> TransformSpec:
 
 
 def _pages(rm: str, sharding) -> dict:
+    """Page arrays at the kernels' shapes."""
+    return {
+        k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
+        for k, s in kernel_pages_shape_dtypes(_spec(rm), ROWS).items()
+    }
+
+
+def _staged(rm: str, sharding) -> dict:
+    """Page arrays as a mesh-less engine stages and puts them."""
     return {
         k: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding)
         for k, s in pages_shape_dtypes(_spec(rm), ROWS).items()
@@ -151,8 +160,26 @@ def test_rm2_presto_program_compiles(one_chip):
     Each fused kernel keeps its instruction name, which a device trace
     reports as the op's name."""
     engine = PreStoEngine(_spec("rm2"), placement="presto", interpret=False)
-    compiled = _compile(engine.preprocess_local, _pages("rm2", one_chip))
+    compiled = _compile(engine.preprocess_local, _staged("rm2", one_chip))
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 0
+    names = set(_INSTRUCTION.findall(compiled.as_text()))
+    assert set(KERNEL_OPS) <= names, set(KERNEL_OPS) - names
+
+
+@pytest.mark.parametrize("rm", ["rm1", "rm2"])
+def test_staged_pages_enter_row_major(one_chip, rm):
+    """The K=1 produce program takes every staged page array in a row-major
+    layout (``major_to_minor`` ascending), the layout of numpy's C order, so
+    the host-to-device put copies bytes without relayout; the kernels keep
+    their instruction names."""
+    engine = PreStoEngine(_spec(rm), placement="presto", interpret=False)
+    pages = _staged(rm, one_chip)
+    compiled = _compile(engine.preprocess_local, pages)
+    (formats,), _ = compiled.input_formats
+    assert set(formats) == set(pages)
+    for name, fmt in formats.items():
+        order = tuple(fmt.layout.major_to_minor)
+        assert order == tuple(range(len(pages[name].shape))), (name, order)
     names = set(_INSTRUCTION.findall(compiled.as_text()))
     assert set(KERNEL_OPS) <= names, set(KERNEL_OPS) - names
