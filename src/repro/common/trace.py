@@ -23,8 +23,9 @@ CLAIM = "presto.claim"
 READ_IO = "presto.read.io"
 READ_VERIFY = "presto.read.verify"
 READ_DECODE = "presto.read.decode"
-# pool worker: ``pages_from_partition`` (and inflation, where a mesh needs it;
-# ``pack_pages`` where there is none)
+# pool worker: ``stage_pages``, with ``stored=1`` where the staged array is
+# the partition's stored body and ``stored=0`` where it was concatenated
+# (``pages_from_partition``, after inflation, where a mesh needs it)
 PAGE_BUILD = "presto.page_build"
 # pool worker: ``stack_pages`` of a chunk (a view at K=1, a copy at K>1)
 STACK = "presto.stack"

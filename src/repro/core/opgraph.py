@@ -35,6 +35,7 @@ bytes of the families it actually sends to hosts.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import time
@@ -45,6 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.spec import TransformSpec
+from repro.data.columnar import REFS_COLUMN, PartitionSchema
+from repro.data.synth import partition_schema
 from repro.kernels import FUSED_KERNELS
 from repro.kernels import ops as K
 from repro.kernels import ref as R
@@ -204,19 +207,9 @@ def build_transform_graph(spec: TransformSpec) -> OpGraph:
     )
 
 
-LANES = 128  # a TPU vector register's lane count
-
-
-def lane_rows(g: int, w: int) -> int:
-    """Whole 128-lane rows holding one feature's ``g*w`` page words."""
-    return -(-g * w // LANES)
-
-
 def page_geometry(cfg, rows: int, unique_rows: int) -> Dict[str, Tuple[int, int, int]]:
     """The kernels' ``(features, row_groups, words)`` shape of each grouped
-    page array of a partition of `rows` (`unique_rows` sparse blocks), in
-    the order a mesh-less engine packs their lane rows
-    (``preprocess.pack_pages``)."""
+    page array of a partition of `rows` (`unique_rows` sparse blocks)."""
     return {
         "dense_words": (cfg.n_dense, rows // 4, 4),
         "sparse_words": (
@@ -226,36 +219,155 @@ def page_geometry(cfg, rows: int, unique_rows: int) -> Dict[str, Tuple[int, int,
     }
 
 
-def kernel_pages(pages: Dict[str, jax.Array], spec: TransformSpec) -> Dict[str, jax.Array]:
-    """View staged page arrays at the kernels' ``(F, G, w)`` shapes (traceable).
+# the staged page buffer of a mesh-less engine: one partition's page words
+PAGE_WORDS = "page_words"
 
-    Mesh-less engines stage the grouped page arrays packed into one
-    lane-dense ``page_rows`` buffer of 128-lane rows
-    (``preprocess.pack_pages``), so the host-to-device put is one plain
-    copy; this cuts each family's region back out on the device, takes each
-    feature's first ``G*w`` words (dropping the zero tail of a partial lane
-    row) and reshapes them.  Pages without ``page_rows`` — meshed engines
-    stage the kernels' shapes — pass through.  Labels ``(..., rows)`` give
-    the geometry, and a leading megabatch axis is kept.  Dedup pages
-    (carrying ``sparse_refs``) hold ``rows / dup_factor`` sparse blocks.
+
+@dataclasses.dataclass(frozen=True)
+class PageLayout:
+    """Where the kernels' page arrays lie in a partition's page words.
+
+    A partition's pages lie back to back in its schema's page order
+    (``PartitionSchema.page_table``), as a file stores its body: each dense
+    column's four byte planes, each sparse column's lengths and values
+    (every bitpacked page ends in one guard word), the labels, and a dedup
+    partition's block refs.  Every offset follows from the schema, so one
+    compiled program cuts every partition of a dataset (``cut_pages``).
     """
-    out = dict(pages)
-    packed = out.pop("page_rows", None)
-    if packed is None:
-        return out
-    labels = pages["label_words"]
-    lead, rows = tuple(labels.shape[:-1]), labels.shape[-1]
-    d = spec.cfg.dup_factor if "sparse_refs" in pages else 1
-    start = 0
-    for name, (f, g, w) in page_geometry(spec.cfg, rows, rows // d).items():
-        r = lane_rows(g, w)
-        region = packed[..., start : start + f * r, :]
-        start += f * r
-        out[name] = region.reshape(*lead, f, r * LANES)[..., : g * w].reshape(
-            *lead, f, g, w
+
+    schema: PartitionSchema
+    table: tuple  # the schema's page table
+    words: int  # every page's words
+    geometry: Dict[str, Tuple[int, int, int]]  # page_geometry of the schema
+    dense: int  # first word of the dense columns, `rows` words each
+    sparse: int  # first word of the sparse columns, `sparse_stride` each
+    sparse_stride: int
+    lengths: int  # a sparse column's lengths page, from the column's start
+    values: int  # its values page, likewise
+    labels: int
+    refs: int | None  # dedup partitions only
+
+    @property
+    def rows(self) -> int:
+        return self.schema.rows
+
+    @staticmethod
+    def of(schema: PartitionSchema, cfg) -> "PageLayout":
+        first: Dict[str, int] = {}  # column -> its first word
+        width: Dict[str, int] = {}  # column -> its words
+        at: Dict[Tuple[str, str], int] = {}  # page -> word from its column's first
+        table = schema.page_table()
+        off = 0
+        for col, page, n in table:
+            first.setdefault(col, off)
+            at[col, page] = off - first[col]
+            width[col] = width.get(col, 0) + n
+            off += n
+
+        def block(prefix: str, count: int) -> Tuple[int, int]:
+            """First word and words per column of `count` columns laid
+            back to back."""
+            names = [f"{prefix}{i}" for i in range(count)]
+            if not names:
+                return 0, 0
+            start, w = first[names[0]], width[names[0]]
+            if any(first[n] != start + i * w or width[n] != w
+                   for i, n in enumerate(names)):
+                raise ValueError(f"{prefix}* columns are not laid back to back")
+            return start, w
+
+        dense, dense_w = block("d", cfg.n_dense)
+        if cfg.n_dense and dense_w != schema.rows:
+            raise ValueError(f"a dense page holds {dense_w} words, not {schema.rows}")
+        sparse, stride = block("s", cfg.n_sparse)
+        return PageLayout(
+            schema=schema,
+            table=table,
+            words=off,
+            geometry=page_geometry(cfg, schema.rows, schema.unique_rows),
+            dense=dense,
+            sparse=sparse,
+            sparse_stride=stride,
+            lengths=at.get(("s0", "lengths"), 0),
+            values=at.get(("s0", "values"), 0),
+            labels=first["label"],
+            refs=first.get(REFS_COLUMN),
         )
-    assert start == packed.shape[-2], (start, packed.shape)
+
+
+@functools.lru_cache(maxsize=None)
+def dataset_layout(cfg, rows: int) -> PageLayout:
+    """The layout of every partition of `rows` rows of dataset `cfg`."""
+    return PageLayout.of(partition_schema(cfg, rows), cfg)
+
+
+@functools.lru_cache(maxsize=None)
+def staged_layout(cfg, words: int) -> PageLayout:
+    """The layout of a partition of dataset `cfg` staged as `words` words.
+
+    The rows are the one number of the schema the dataset leaves open, and
+    the words fix them: rows come in steps of 32 sparse blocks, and each
+    step adds the same number of words.
+    """
+    step = 32 * cfg.dup_factor
+    base = dataset_layout(cfg, step).words
+    per_step = dataset_layout(cfg, 2 * step).words - base
+    steps, extra = divmod(words - base, per_step)
+    if steps < 0 or extra:
+        raise ValueError(f"no partition of {cfg.name} has {words} page words")
+    return dataset_layout(cfg, step * (steps + 1))
+
+
+def cut_pages(words, layout: PageLayout) -> Dict[str, Any]:
+    """The kernels' page arrays cut out of page words (traceable).
+
+    `words` is ``(..., layout.words)`` u32, a leading megabatch axis kept.
+    Dense data pages ``(F, 4, G)`` turn to ``(F, G, 4)``; each sparse
+    column's lengths and values pages lose their guard word; the refs page
+    is bitcast to int32.  A numpy array gives numpy views.
+    """
+    lead = tuple(words.shape[:-1])
+    rows = layout.rows
+    f, g, _ = layout.geometry["dense_words"]
+    dense = words[..., layout.dense : layout.dense + f * rows]
+    out = {"dense_words": dense.reshape(*lead, f, 4, g).swapaxes(-1, -2)}
+    f = layout.geometry["sparse_words"][0]
+    sparse = words[..., layout.sparse : layout.sparse + f * layout.sparse_stride]
+    sparse = sparse.reshape(*lead, f, layout.sparse_stride)
+    for name, at in (("sparse_words", layout.values), ("length_words", layout.lengths)):
+        f, g, w = layout.geometry[name]
+        out[name] = sparse[..., at : at + g * w].reshape(*lead, f, g, w)
+    out["label_words"] = words[..., layout.labels : layout.labels + rows]
+    if layout.refs is not None:
+        refs = words[..., layout.refs : layout.refs + rows]
+        out["sparse_refs"] = (
+            refs.view(np.int32) if isinstance(refs, np.ndarray)
+            else jax.lax.bitcast_convert_type(refs, jnp.int32)
+        )
     return out
+
+
+def kernel_pages(pages: Dict[str, jax.Array], spec: TransformSpec) -> Dict[str, jax.Array]:
+    """View staged pages at the kernels' ``(F, G, w)`` shapes (traceable).
+
+    The one place where staged pages enter a compiled body.  A mesh-less
+    engine stages a partition's page words (``PAGE_WORDS``, ``(..., words)``
+    u32, a leading megabatch axis kept), which ``cut_pages`` cuts by the
+    dataset's layout.  Pages already at the kernels' shapes, as meshed
+    engines stage them, pass through.
+    """
+    words = pages.get(PAGE_WORDS)
+    if words is None:
+        return dict(pages)
+    return cut_pages(words, staged_layout(spec.cfg, words.shape[-1]))
+
+
+def staged_rows(pages: Dict[str, jax.Array], spec: TransformSpec) -> int:
+    """Rows of one partition of staged pages, in either form."""
+    words = pages.get(PAGE_WORDS)
+    if words is None:
+        return int(pages["label_words"].shape[-1])
+    return staged_layout(spec.cfg, words.shape[-1]).rows
 
 
 def prepare_env(pages: Dict[str, jax.Array], spec: TransformSpec) -> Dict[str, Any]:

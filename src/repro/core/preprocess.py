@@ -20,26 +20,27 @@ Everything here is jit-able and shard_map-able; shapes are static given a
 
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.opgraph import (
-    LANES,
+    PAGE_WORDS,
     LoweredPlan,
+    PageLayout,
     build_transform_graph,
+    cut_pages,
+    dataset_layout,
     kernel_pages,
-    lane_rows,
     lower,
     page_geometry,
     prepare_env,
     resolve_placements,
 )
 from repro.core.spec import TransformSpec
-from repro.data.columnar import Partition, partition_refs
-from repro.kernels import ops as K
+from repro.data.columnar import Partition
 
 MiniBatch = Dict[str, jax.Array]
 
@@ -48,59 +49,50 @@ MiniBatch = Dict[str, jax.Array]
 # Host-side page staging: Partition (numpy, flat pages) -> staged layout
 
 
-def pack_pages(part: Partition, spec: TransformSpec) -> Dict[str, np.ndarray]:
-    """Lay one partition's pages out as a mesh-less engine stages and puts them.
+def partition_words(part: Partition, layout: PageLayout) -> Tuple[np.ndarray, bool]:
+    """A partition's page words in its schema's page order, and whether they
+    are the stored body it was read as (a view, no copy).
 
-    Every ``jax.device_put`` array costs the host a fixed time (and, beside
-    a second worker, a wait for the interpreter lock) however few its
-    bytes, so the grouped pages cross as ONE ``page_rows`` array: each
-    feature's regrouped words (the kernels' ``(G, w)`` layout, row-major)
-    as whole 128-lane rows, the tail of a partial row zero, families in
-    ``opgraph.page_geometry`` order.  A row-major ``(R, 128)`` array is also
-    the TPU's own layout, byte for byte numpy's C order, so the put is a
-    plain copy with no relayout.  Labels stay flat ``(rows,)``.  The
-    compiled program cuts the kernels' arrays back out
-    (``opgraph.kernel_pages``).
-
-    Dedup partitions (``schema.dup_factor > 1``) stage their sparse/length
-    pages at UNIQUE-block geometry — each shared block's encoded words enter
-    device memory exactly once — plus a ``sparse_refs`` vector mapping the
-    ``rows`` logical samples back to blocks; the compiled Transform
-    gather-expands after hashing (``execute_plan``).
+    Every ``jax.device_put`` array costs the host a fixed time however few
+    its bytes, so a mesh-less engine stages a partition as this ONE u32
+    array and the compiled program cuts the kernels' arrays out of it
+    (``opgraph.kernel_pages``).  A file stores its pages in this order, so a
+    partition read from one stages its body as is; a partition built in
+    memory (a synthetic source, an inflated dedup partition) is
+    concatenated in the same order.  A body laid out otherwise raises: it
+    is never mis-cut.
     """
-    cfg = spec.cfg
-    rows = part.schema.rows
-    u = part.schema.unique_rows  # == rows for classic partitions
-    columns = {
-        "dense_words": lambda i: K.regroup_bytesplit(
-            part.columns[f"d{i}"].pages["data"], rows
-        ),
-        "sparse_words": lambda i: K.regroup_bitpack(
-            part.columns[f"s{i}"].pages["values"], u * cfg.max_sparse_len,
-            cfg.id_width,
-        ),
-        "length_words": lambda i: K.regroup_bitpack(
-            part.columns[f"s{i}"].pages["lengths"], u, cfg.len_width
-        ),
-    }
-    geometry = page_geometry(cfg, rows, u)
-    n = sum(f * lane_rows(g, w) for f, g, w in geometry.values())
-    page_rows = np.zeros((n, LANES), np.uint32)
-    words = page_rows.reshape(-1)
-    start = 0
-    for name, (f, g, w) in geometry.items():
-        stride = lane_rows(g, w) * LANES
-        for i in range(f):
-            words[start : start + g * w] = columns[name](i).reshape(-1)
-            start += stride
-    pages = {
-        "page_rows": page_rows,  # (R, 128) u32
-        "label_words": part.columns["label"].pages["data"][:rows],  # (rows,) u32
-    }
-    refs = partition_refs(part)
-    if refs is not None:
-        pages["sparse_refs"] = refs.astype(np.int32)  # (rows,) block index
-    return pages
+    if part.body is not None:
+        if part.body_table != layout.table:
+            raise ValueError(
+                f"partition {part.partition_id}: its stored pages are not in "
+                "the dataset schema's page order"
+            )
+        return part.body, True
+    pages = [part.columns[c].pages[p] for c, p, _ in layout.table]
+    if [a.size for a in pages] != [n for *_, n in layout.table]:
+        raise ValueError(
+            f"partition {part.partition_id}: its pages are not the sizes of "
+            "the dataset schema's"
+        )
+    return np.concatenate(pages), False
+
+
+def stage_pages(part: Partition, spec: TransformSpec) -> Tuple[Dict[str, np.ndarray], bool]:
+    """One partition's staged pages, as a mesh-less engine puts them:
+    ``{PAGE_WORDS: (words,) u32}``, and whether that is its stored body.
+
+    The partition's schema must be the dataset's (``spec.cfg``) at its
+    rows, the layout the compiled program cuts by; another raises.
+    """
+    layout = dataset_layout(spec.cfg, part.schema.rows)
+    if part.schema != layout.schema:
+        raise ValueError(
+            f"partition {part.partition_id}: its schema is not the schema of "
+            f"dataset {spec.cfg.name}"
+        )
+    words, stored = partition_words(part, layout)
+    return {PAGE_WORDS: words}, stored
 
 
 def pages_from_partition(part: Partition, spec: TransformSpec) -> Dict[str, np.ndarray]:
@@ -108,9 +100,12 @@ def pages_from_partition(part: Partition, spec: TransformSpec) -> Dict[str, np.n
     them: ``dense_words`` ``(n_dense, rows/4, 4)``, ``sparse_words``
     ``(n_sparse, u*L/32, w)``, ``length_words`` ``(n_sparse, u/32, lw)``
     u32, ``label_words`` ``(rows,)`` u32 (and ``sparse_refs`` where the
-    partition dedups) — ``pack_pages`` seen through ``opgraph.kernel_pages``.
+    partition dedups) — its page words cut as the compiled program cuts
+    them (``opgraph.cut_pages``), by the partition's own schema.
     """
-    return kernel_pages(pack_pages(part, spec), spec)
+    layout = PageLayout.of(part.schema, spec.cfg)
+    words, _ = partition_words(part, layout)
+    return {k: np.ascontiguousarray(v) for k, v in cut_pages(words, layout).items()}
 
 
 def stack_pages(pages_list) -> Dict[str, np.ndarray]:
@@ -173,18 +168,10 @@ def megabatch_pages_shape_dtypes(
 
 
 def pages_shape_dtypes(spec: TransformSpec, rows: int) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct stand-ins for the staged page arrays (dry-run inputs):
-    packed (``pack_pages``), as mesh-less engines stage them."""
-    cfg = spec.cfg
-    geometry = page_geometry(cfg, rows, rows // getattr(cfg, "dup_factor", 1))
-    out = {
-        name: s
-        for name, s in kernel_pages_shape_dtypes(spec, rows).items()
-        if name not in geometry
-    }
-    n = sum(f * lane_rows(g, w) for f, g, w in geometry.values())
-    out["page_rows"] = jax.ShapeDtypeStruct((n, LANES), jnp.uint32)
-    return out
+    """ShapeDtypeStruct stand-ins for the staged page arrays (dry-run inputs),
+    as mesh-less engines stage them (``stage_pages``)."""
+    words = dataset_layout(spec.cfg, rows).words
+    return {PAGE_WORDS: jax.ShapeDtypeStruct((words,), jnp.uint32)}
 
 
 def kernel_pages_shape_dtypes(

@@ -55,19 +55,21 @@ from repro.core.opgraph import (
     ISP,
     LoweredPlan,
     build_transform_graph,
+    kernel_pages,
     lower,
     prepare_env,
     resolve_placements,
+    staged_rows,
 )
 from repro.core.preprocess import (
     MiniBatch,
     execute_plan,
     flatten_megabatch,
     kernel_pages_shape_dtypes,
-    pack_pages,
     pages_from_partition,
     pages_shape_dtypes,
     stack_pages,
+    stage_pages,
 )
 from repro.core.spec import TransformSpec
 from repro.data.columnar import inflate_partition
@@ -296,9 +298,9 @@ class PreStoEngine:
             inner = self.preprocess_megabatch
 
             def body(stacked):
-                k = stacked["label_words"].shape[0]
+                k = next(iter(stacked.values())).shape[0]
                 EXECUTABLES.note_trace(
-                    key, k=int(k), rows=int(stacked["label_words"].shape[1])
+                    key, k=int(k), rows=staged_rows(stacked, self.spec)
                 )
                 return inner(stacked)
 
@@ -306,9 +308,7 @@ class PreStoEngine:
         if self.mesh is None:
 
             def body(pages):
-                EXECUTABLES.note_trace(
-                    key, k=1, rows=int(pages["label_words"].shape[0])
-                )
+                EXECUTABLES.note_trace(key, k=1, rows=staged_rows(pages, self.spec))
                 return self.preprocess_local(pages)
 
             return jax.jit(body, donate_argnums=(0,) if self._donate else ())
@@ -318,9 +318,7 @@ class PreStoEngine:
         }
 
         def body(pages):
-            EXECUTABLES.note_trace(
-                key, k=1, rows=int(pages["label_words"].shape[0])
-            )
+            EXECUTABLES.note_trace(key, k=1, rows=staged_rows(pages, self.spec))
             return self.preprocess_global(pages)
 
         return jax.jit(body, in_shardings=(in_sh,), out_shardings=out_sh)
@@ -367,7 +365,7 @@ class PreStoEngine:
         Traceable; mesh-less engines only (the pool-worker produce path).
         """
         assert self.mesh is None, "megabatching is a local (per-unit) launch"
-        k = int(stacked["label_words"].shape[0])
+        k = int(next(iter(stacked.values())).shape[0])
         assert k == 1 or self.lowered_plan.megabatch_safe(), (
             "lowered plan has a non-row-local stage; megabatch would not be "
             "bitwise identical to solo runs"
@@ -401,23 +399,27 @@ class PreStoEngine:
     def stage_partition(self, store: PartitionedStore, pid: int) -> Dict[str, np.ndarray]:
         """Extract(Read): fetch + lay out one partition's pages (host side).
 
-        Mesh-less engines pack the grouped page arrays into one lane-dense
-        buffer (``preprocess.pack_pages``), so the host-to-device put is
-        one plain copy; the compiled program cuts the kernels' shapes back
-        out.  Meshed engines shard pages along the row-group axis
-        (``pages_pspec``), which must stay aligned with rows, so they keep
-        the kernels' ``(F, G, w)`` shapes — and a dedup partition's
-        unique-geometry pages would break that row alignment, so those
-        inflate (``columnar.inflate_partition``, bitwise faithful) to the
-        classic per-sample layout first.  The I/O ledger still charges only
-        the UNIQUE bytes (``store.read`` streams the stored form; inflation
-        is host-side decompression after the read).
+        Mesh-less engines stage the partition's page words as ONE u32 array
+        (``preprocess.stage_pages``): a partition read from a file stages
+        the body it was read as, with no copy, so the host-to-device put is
+        one plain copy; the compiled program cuts the kernels' shapes out.
+        The page-build span says which (``stored=1``: the stored body;
+        ``0``: concatenated).  Meshed engines shard pages along the
+        row-group axis (``pages_pspec``), which must stay aligned with
+        rows, so they keep the kernels' ``(F, G, w)`` shapes — and a dedup
+        partition's unique-geometry pages would break that row alignment,
+        so those inflate (``columnar.inflate_partition``, bitwise faithful)
+        to the classic per-sample layout first.  The I/O ledger still
+        charges only the UNIQUE bytes (``store.read`` streams the stored
+        form; inflation is host-side decompression after the read).
         """
         part = store.read(pid)
-        with TraceAnnotation(trace.PAGE_BUILD, pid=pid):
+        with TraceAnnotation(trace.PAGE_BUILD, pid=pid) as span:
             if self.mesh is not None:
                 return pages_from_partition(inflate_partition(part), self.spec)
-            return pack_pages(part, self.spec)
+            pages, stored = stage_pages(part, self.spec)
+            span.set_metadata(stored=int(stored))
+            return pages
 
     def stage_megabatch(
         self, store: PartitionedStore, pids: Sequence[int]
@@ -596,6 +598,11 @@ class PreStoEngine:
                     self._jit_rest = build()
         return self._jit_rest
 
+    def staged_refs(self, pages: Dict[str, np.ndarray]) -> Optional[np.ndarray]:
+        """The ``(rows,)`` block refs of a dedup partition's staged pages
+        (host side), else None."""
+        return kernel_pages(pages, self.spec).get("sparse_refs")
+
     @staticmethod
     def extract_blocks(
         batch: MiniBatch, refs: np.ndarray
@@ -625,7 +632,7 @@ class PreStoEngine:
         sparse pages' decode+hash work is what the block cache saved.
         Bitwise identical to a cold produce of the same partition.
         """
-        refs = np.asarray(pages["sparse_refs"], dtype=np.int64)
+        refs = np.asarray(self.staged_refs(pages), dtype=np.int64)
         batch = dict(self.jit_preprocess_rest_cached()(pages))
         batch["multi_hot_ids"] = jnp.asarray(np.asarray(block_ids)[refs])
         batch["lengths"] = jnp.asarray(np.asarray(block_lens)[refs])

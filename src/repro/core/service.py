@@ -1233,7 +1233,7 @@ class Session:
             pages = engine.stage_partition(store, pid)
         except _STORAGE_MISSES:
             return None
-        if "sparse_refs" not in pages:
+        if engine.staged_refs(pages) is None:
             return None
         batch = engine.assemble_from_blocks(pages, *blocks)
         jax.block_until_ready(batch)

@@ -40,8 +40,9 @@ import dataclasses
 import hashlib
 import io
 import json
+import os
 import struct
-from typing import Dict, List, Mapping
+from typing import Dict, List, Mapping, Optional
 
 import numpy as np
 
@@ -127,6 +128,15 @@ class PartitionSchema:
     def encoded_words(self) -> int:
         return sum(sum(self.page_sizes(c).values()) for c in self.columns)
 
+    def page_table(self) -> tuple:
+        """``(column, page, words)`` of every page, in the order
+        ``encode_partition`` builds and ``write_partition`` stores them."""
+        return tuple(
+            (c.name, page, words)
+            for c in self.columns
+            for page, words in self.page_sizes(c).items()
+        )
+
     def logical_schema(self) -> "PartitionSchema":
         """The undeduped (dup_factor 1, no refs column) view of this schema —
         the layout the same logical rows would occupy without dedup."""
@@ -170,6 +180,11 @@ class Partition:
     partition_id: int
     schema: PartitionSchema
     columns: Dict[str, EncodedColumn]
+    # A partition read from a file: the stored body its pages view, every
+    # page's u32 words back to back, and the file's page table (``(column,
+    # page, words)`` in body order).  None for pages built in memory.
+    body: Optional[np.ndarray] = None
+    body_table: Optional[tuple] = None
 
     def nbytes(self) -> int:
         """Actual stored bytes — UNIQUE block bytes for a dedup partition.
@@ -512,10 +527,23 @@ def _read_file(path: str):
             partition_id = header["partition_id"]
         except (ValueError, KeyError, TypeError, AssertionError) as e:
             raise CorruptPartitionFile(f"{path}: corrupt header: {e}") from e
-        return partition_id, schema, pages, header.get("checksum"), f.read()
+        return partition_id, schema, pages, header.get("checksum"), _read_body(f)
 
 
-def _verify(path: str, want_ck: str | None, body: bytes) -> None:
+def _read_body(f) -> np.ndarray:
+    """The rest of an open file, read straight into fresh u32 words (the
+    tail of a partial last word zero) and returned as their ``(n,)`` u8
+    bytes: the pages a partition then views need no copy, and their words
+    are aligned as numpy aligns an array."""
+    n = max(os.fstat(f.fileno()).st_size - f.tell(), 0)
+    words = np.empty(-(-n // 4), np.uint32)
+    if n % 4:
+        words[-1] = 0
+    got = f.readinto(words.view(np.uint8)[:n])
+    return words.view(np.uint8)[:got]
+
+
+def _verify(path: str, want_ck: str | None, body: np.ndarray) -> None:
     if want_ck is not None:
         got_ck = hashlib.sha256(body).hexdigest()[:16]
         if got_ck != want_ck:
@@ -526,10 +554,13 @@ def _verify(path: str, want_ck: str | None, body: bytes) -> None:
 
 
 def _decode(
-    path: str, partition_id: int, schema: PartitionSchema, pages: list, body: bytes
+    path: str, partition_id: int, schema: PartitionSchema, pages: list,
+    body: np.ndarray,
 ) -> Partition:
     cols: Dict[str, EncodedColumn] = {}
     cschemas = {c.name: c for c in schema.columns}
+    words = np.frombuffer(body, dtype=np.uint32, count=len(body) // 4)
+    table = []
     off = 0
     for pmeta in pages:
         try:
@@ -539,15 +570,17 @@ def _decode(
             cs = cschemas[cname]
         except (KeyError, TypeError, ValueError) as e:
             raise CorruptPartitionFile(f"{path}: corrupt page table: {e}") from e
-        end = off + nwords * 4
-        if nwords < 0 or end > len(body):
+        end = off + nwords
+        if nwords < 0 or end > words.size:
             raise CorruptPartitionFile(
                 f"{path}: truncated payload (page {cname}/{pname} wants "
-                f"bytes [{off}, {end}) of {len(body)})"
+                f"bytes [{off * 4}, {end * 4}) of {len(body)})"
             )
-        words = np.frombuffer(body, dtype=np.uint32, count=nwords, offset=off)
-        off = end
         if cname not in cols:
             cols[cname] = EncodedColumn(cs, {})
-        cols[cname].pages[pname] = words
-    return Partition(partition_id, schema, cols)
+        cols[cname].pages[pname] = words[off:end]
+        table.append((cname, pname, nwords))
+        off = end
+    return Partition(
+        partition_id, schema, cols, body=words[:off], body_table=tuple(table)
+    )

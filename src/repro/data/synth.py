@@ -91,7 +91,8 @@ class RawBatch:
     sparse_refs: np.ndarray | None = None
 
 
-def _schema_for(cfg: RMDataConfig, rows: int) -> PartitionSchema:
+def partition_schema(cfg: RMDataConfig, rows: int) -> PartitionSchema:
+    """The schema of every partition of `rows` rows of dataset `cfg`."""
     cols = []
     for i in range(cfg.n_dense):
         cols.append(ColumnSchema(f"d{i}", "dense", cfg.dense_encoding))
@@ -132,7 +133,7 @@ class SyntheticRecSysSource:
                 f"rows={self.rows} needs rows/dup_factor divisible by 32 "
                 f"(dup_factor={cfg.dup_factor})"
             )
-        self.schema = _schema_for(cfg, self.rows)
+        self.schema = partition_schema(cfg, self.rows)
         self._pool_cache: Dict[int, tuple] = {}  # pool block id -> (ids, lens)
         # Dataset-level bucket boundaries (one sorted array per generated
         # feature) drawn from the dense-feature distribution's range.

@@ -21,7 +21,7 @@ from repro.core.costmodel import (
 )
 from repro.core.featcache import BlockKey, FeatureCache
 from repro.core.opgraph import family_page_bytes
-from repro.core.preprocess import pack_pages, pages_from_partition, stack_pages
+from repro.core.preprocess import pages_from_partition, stack_pages, stage_pages
 from repro.core.presto import PreStoEngine
 from repro.core.service import JobSpec, PreprocessingService
 from repro.core.simclock import synthetic_costs
@@ -164,7 +164,7 @@ def test_megabatch_bitwise_vs_solo(dedup4):
 def test_pages_struct_matches_dedup_pages(dedup4):
     cfg, src, spec = dedup4
     eng = PreStoEngine(spec, interpret=True)
-    pages = pack_pages(src.partition(0), spec)
+    pages, _stored = stage_pages(src.partition(0), spec)
     structs = eng.pages_struct(cfg.rows_per_partition)
     assert set(structs) == set(pages)
     for k, s in structs.items():

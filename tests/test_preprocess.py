@@ -6,11 +6,11 @@ import numpy as np
 import pytest
 
 from repro.core.preprocess import (
-    pack_pages,
     pages_from_partition,
     pages_shape_dtypes,
     preprocess_pages,
     stage_functions,
+    stage_pages,
 )
 from repro.core.spec import TransformSpec
 from repro.data.synth import RMDataConfig, SyntheticRecSysSource
@@ -73,7 +73,7 @@ def test_stage_functions_compose(small_rm):
 
 def test_pages_shape_dtypes_match(small_rm):
     src, spec = small_rm
-    pages = pack_pages(src.partition(0), spec)
+    pages, _stored = stage_pages(src.partition(0), spec)
     struct = pages_shape_dtypes(spec, 256)
     assert set(struct) == set(pages)
     for k in pages:

@@ -169,17 +169,19 @@ def test_rm2_presto_program_compiles(one_chip):
 
 @pytest.mark.parametrize("rm", ["rm1", "rm2"])
 def test_staged_pages_enter_row_major(one_chip, rm):
-    """The K=1 produce program takes every staged page array in a row-major
-    layout (``major_to_minor`` ascending), the layout of numpy's C order, so
-    the host-to-device put copies bytes without relayout; the kernels keep
-    their instruction names."""
+    """The K=1 produce program takes exactly one page parameter, the
+    partition's u32 page words, in a row-major (or 1-D) layout
+    (``major_to_minor`` ascending), the layout of numpy's C order, so the
+    host-to-device put copies the stored body without relayout; the kernels
+    keep their instruction names."""
     engine = PreStoEngine(_spec(rm), placement="presto", interpret=False)
     pages = _staged(rm, one_chip)
+    (name,) = pages
+    assert pages[name].dtype == jnp.uint32
     compiled = _compile(engine.preprocess_local, pages)
     (formats,), _ = compiled.input_formats
-    assert set(formats) == set(pages)
-    for name, fmt in formats.items():
-        order = tuple(fmt.layout.major_to_minor)
-        assert order == tuple(range(len(pages[name].shape))), (name, order)
+    assert set(formats) == {name}
+    order = tuple(formats[name].layout.major_to_minor)
+    assert order == tuple(range(len(pages[name].shape))), order
     names = set(_INSTRUCTION.findall(compiled.as_text()))
     assert set(KERNEL_OPS) <= names, set(KERNEL_OPS) - names

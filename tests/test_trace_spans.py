@@ -3,9 +3,10 @@
 A small engine-backed session is served by a pool of two workers under
 ``jax.profiler.trace`` and the captured ``.xplane.pb`` is read back: every
 span of ``repro.common.trace`` appears, the storage-read spans number the
-partitions, the dispatch spans number the session's ``launches``, the spans
-are leaves (none overlaps another on its thread), and the idle and
-consumer-wait spans sit on the threads they belong to.
+partitions, every page build stages the stored body it read (``stored=1``),
+the dispatch spans number the session's ``launches``, the spans are leaves
+(none overlaps another on its thread), and the idle and consumer-wait spans
+sit on the threads they belong to.
 """
 
 import collections
@@ -88,6 +89,9 @@ def test_served_spans(served, tmp_path, k):
                  trace.PAGE_BUILD):
         pids = sorted(st["pid"] for n, _s, _e, st in spans if n == name)
         assert pids == list(range(PARTITIONS)), name
+    # every partition is read from its file and staged as the body it was read as
+    assert [st["stored"] for n, _s, _e, st in spans if n == trace.PAGE_BUILD] == [
+        1] * PARTITIONS
     dispatches = [st for n, _s, _e, st in spans if n == trace.DISPATCH]
     assert len(dispatches) == stats.launches
     assert all(st["k"] == k for st in dispatches)
